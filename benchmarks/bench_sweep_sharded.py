@@ -2,28 +2,27 @@
 
 Measures the mesh-sharded batched refactorize+solve engine (``GLU(...,
 mesh=make_sweep_mesh(d))``) at a fixed batch size while the device count
-grows.  Each device count runs in a fresh subprocess because the emulated
-host-device topology (``XLA_FLAGS=--xla_force_host_platform_device_count``)
-is fixed at jax import time and cannot change within a process.
+grows.  Everything runs in ONE process: each device count is a sub-mesh of
+``jax.devices()`` (the first ``d`` devices), so on a TPU host one process
+holds every chip and no child ever competes for one.  Counts larger than
+the devices present are skipped.
 
-On a multi-core host the curve shows the shard_map data-parallel speedup;
-on a single-core container the emulated devices time-share one core, so
-the honest expectation is ~1x (the row notes ``cpu_count`` so readers can
-tell which regime produced the numbers).  Every run still asserts the
-single-dispatch invariant: each shard executes the whole fused schedule in
-ONE device dispatch (``n_dispatches == 1`` and ``solve_dispatches == 1``).
+On the CPU, emulate a multi-device host by setting
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before JAX starts;
+the emulated devices time-share the host's cores, so the honest expectation
+there is ~1x.  Every row names the platform and device kind it ran on.
+Every run still asserts the single-dispatch invariant: each shard executes
+the whole fused schedule in ONE device dispatch (``n_dispatches == 1`` and
+``solve_dispatches == 1``).
 
 Row names use the ``sweep_sharded_`` prefix, which is intentionally NOT in
-the perf-diff gate (``benchmarks.diff`` gates ``factorize_``/``ac_``) —
-multi-device emulation timing is far too host-dependent to gate on.
+the perf-diff gate (``benchmarks.diff`` gates ``factorize_``/``ac_``).
 """
 from __future__ import annotations
 
-import json
 import os
-import subprocess
 import sys
-from pathlib import Path
+import time
 
 from .common import row
 
@@ -32,27 +31,18 @@ BATCH = 64
 REPEATS = 3
 CIRCUIT_N = 600
 
-_ROOT = Path(__file__).resolve().parent.parent
 
-
-def _child(n_devices: int, batch: int, repeats: int, size: int) -> None:
-    """Subprocess body: build one sweep problem, time refactorize_solve.
-
-    Runs with XLA_FLAGS already set by the parent, so jax sees
-    ``n_devices`` emulated host devices."""
-    import time
-
-    import jax
+def _time_sweep(n_devices: int, batch: int, repeats: int, size: int) -> dict:
+    """Build one sweep problem on the first ``n_devices`` devices and time
+    its refactorize_solve (the result comes back to the host, so each
+    timing ends after the device finished)."""
     import numpy as np
 
     from repro.core import GLU
     from repro.distributed import make_sweep_mesh
     from repro.sparse import circuit_jacobian
 
-    assert jax.device_count() >= n_devices, (
-        f"expected >= {n_devices} devices, got {jax.device_count()}")
     mesh = make_sweep_mesh(n_devices) if n_devices > 1 else None
-
     A = circuit_jacobian(size, avg_degree=4.5, seed=5)
     glu = GLU(A, mesh=mesh)
 
@@ -68,57 +58,32 @@ def _child(n_devices: int, batch: int, repeats: int, size: int) -> None:
         glu.refactorize_solve(vals, rhs)
         ts.append(time.perf_counter() - t0)
     info = glu.solve_info
-    print("RESULT " + json.dumps({
-        "elapsed_s": min(ts),
-        "n_devices": info["n_devices"],
-        "batch_spec": info["batch_spec"],
-        "n_dispatches": info["n_dispatches"],
-        "solve_dispatches": info["solve_dispatches"],
-        "n": A.n,
-        "nnz_filled": glu.nnz_filled,
-    }), flush=True)
-
-
-def _run_child(n_devices: int, batch: int, repeats: int, size: int) -> dict:
-    env = os.environ.copy()
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
-    env["JAX_PLATFORMS"] = "cpu"
-    env["JAX_ENABLE_X64"] = "1"
-    src = str(_ROOT / "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    cmd = [sys.executable, "-m", "benchmarks.bench_sweep_sharded",
-           "--child", str(n_devices), str(batch), str(repeats), str(size)]
-    proc = subprocess.run(cmd, cwd=str(_ROOT), env=env,
-                          capture_output=True, text=True)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"sharded-sweep child (d={n_devices}) failed:\n{proc.stderr}")
-    for line in reversed(proc.stdout.splitlines()):
-        if line.startswith("RESULT "):
-            return json.loads(line[len("RESULT "):])
-    raise RuntimeError(
-        f"sharded-sweep child (d={n_devices}) printed no RESULT line:\n"
-        f"{proc.stdout}")
+    return {"elapsed_s": min(ts), "n_devices": info["n_devices"],
+            "batch_spec": info["batch_spec"],
+            "n_dispatches": info["n_dispatches"],
+            "solve_dispatches": info["solve_dispatches"]}
 
 
 def main(smoke: bool = False):
-    counts = [1, 2] if smoke else DEVICE_COUNTS
+    import jax
+
+    devices = jax.devices()
+    platform, kind = devices[0].platform, devices[0].device_kind
+    counts = [d for d in ([1, 2] if smoke else DEVICE_COUNTS)
+              if d <= len(devices)]
     batch = 8 if smoke else BATCH
     repeats = 1 if smoke else REPEATS
     size = 200 if smoke else CIRCUIT_N
     cores = os.cpu_count() or 1
 
-    print(f"# sweep_sharded: B={batch} refactorize_solve, emulated host "
-          f"devices (physical cores: {cores})")
-    if cores < max(counts):
-        print(f"# NOTE: {cores} core(s) < {max(counts)} devices — emulated "
-              f"shards time-share cores; expect ~1x, not linear scaling")
+    print(f"# sweep_sharded: B={batch} refactorize_solve on {platform} "
+          f"({kind}), {len(devices)} device(s), {cores} host cores")
     print("# devices,us_per_matrix,speedup_vs_d1,n_dispatches")
 
     per_matrix_d1 = None
     results = []
     for d in counts:
-        r = _run_child(d, batch, repeats, size)
+        r = _time_sweep(d, batch, repeats, size)
         assert r["n_devices"] == d, r
         assert r["n_dispatches"] == 1, r
         assert r["solve_dispatches"] == 1, r
@@ -129,7 +94,8 @@ def main(smoke: bool = False):
         print(f"{d},{per_matrix * 1e6:.1f},{speedup:.2f},1", flush=True)
         row(f"sweep_sharded_d{d}", per_matrix * 1e6,
             f"batch={batch} speedup_vs_d1={speedup:.2f}x "
-            f"spec={r['batch_spec']} dispatches=1 cores={cores}")
+            f"spec={r['batch_spec']} dispatches=1 platform={platform} "
+            f"device_kind={kind} cores={cores}")
         results.append({"devices": d, "per_matrix_s": per_matrix,
                         "speedup_vs_d1": speedup})
     best = max(results, key=lambda r: r["speedup_vs_d1"])
@@ -139,9 +105,4 @@ def main(smoke: bool = False):
 
 
 if __name__ == "__main__":
-    if "--child" in sys.argv:
-        i = sys.argv.index("--child")
-        d, b, r, n = (int(v) for v in sys.argv[i + 1:i + 5])
-        _child(d, b, r, n)
-    else:
-        main(smoke="--smoke" in sys.argv)
+    main(smoke="--smoke" in sys.argv)

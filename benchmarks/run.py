@@ -52,7 +52,7 @@ def _sections():
          "=== Sparse-RHS trisolve: reach-pruned vs full schedule ===",
          bench_sparse_rhs.main),
         ("sweep_sharded",
-         "=== Sharded sweep scaling (emulated multi-device) ===",
+         "=== Sharded sweep scaling over sub-meshes of jax.devices() ===",
          bench_sweep_sharded.main),
     ]
 
@@ -75,8 +75,11 @@ def main(argv=None) -> None:
                          f"{[name for name, _, _ in sections]}")
         sections = [s for s in sections if s[0] in wanted]
 
+    from repro.compile_cache import enable_compile_cache
+
     from .common import RESULTS
 
+    enable_compile_cache()
     RESULTS.clear()     # a second in-process main() must not accumulate rows
     print("name,us_per_call,derived")
     for _, header, fn in sections:

@@ -14,9 +14,11 @@ os.environ.setdefault("JAX_ENABLE_X64", "1")
 import numpy as np
 
 from repro.circuit import rc_grid_circuit, ac_sweep
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ckt = rc_grid_circuit(8, 8, with_diodes=True, seed=0)
     ckt.add_ac_current_source(1, 0, 1.0)   # 1A small-signal probe at node 1
     freqs = np.logspace(0, 5, 21)

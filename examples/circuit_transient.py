@@ -12,9 +12,11 @@ os.environ.setdefault("JAX_ENABLE_X64", "1")
 import numpy as np
 
 from repro.circuit import rc_grid_circuit, transient
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ckt = rc_grid_circuit(10, 10, with_diodes=True, seed=0)
     print(f"grid 10x10: {ckt.n} nodes, {len(ckt.resistors)} R, "
           f"{len(ckt.capacitors)} C, {len(ckt.diodes)} diodes, "

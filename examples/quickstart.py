@@ -10,11 +10,13 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import GLU
 from repro.sparse import circuit_jacobian
 
 
 def main():
+    enable_compile_cache()
     # a 2000-node circuit-style sparse matrix (structurally symmetric-ish,
     # diagonally dominant — what MNA assembly produces)
     A = circuit_jacobian(2000, avg_degree=4.0, seed=0)

@@ -14,9 +14,11 @@ os.environ.setdefault("JAX_ENABLE_X64", "1")
 import numpy as np
 
 from repro.circuit import rc_grid_circuit, transient_sweep
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ckt = rc_grid_circuit(8, 8, with_diodes=True, seed=0)
     scales = np.linspace(0.8, 1.2, 9)   # ±20% conductance corners
     print(f"grid 8x8: {ckt.n} nodes, sweeping {len(scales)} corners "

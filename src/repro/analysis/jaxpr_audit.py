@@ -26,6 +26,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from .report import VerifyReport
 
@@ -41,13 +42,12 @@ _DONOR_MARKERS = ("tf.aliasing_output", "jax.buffer_donor")
 
 
 def _iter_subjaxprs(params: dict):
-    core = jax.core
     for v in params.values():
         vs = v if isinstance(v, (tuple, list)) else (v,)
         for x in vs:
-            if isinstance(x, core.ClosedJaxpr):
+            if isinstance(x, ClosedJaxpr):
                 yield x.jaxpr
-            elif isinstance(x, core.Jaxpr):
+            elif isinstance(x, Jaxpr):
                 yield x
 
 

@@ -38,6 +38,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from ..distributed.scenario import make_scenario_sharding
+from ..kernels.backend import on_tpu
 from ..sparse.csc import CSC
 from ..sparse.layout import resolve_layout, unpack_planes
 from .factorize import JaxFactorizer
@@ -60,8 +61,16 @@ def resolve_value_dtype(dtype) -> np.dtype:
     and complex128 -> complex64.  Silent truncation on the solve path is a
     correctness bug (observed: residual 4.5e-7 on a float64 request), so a
     truncated request raises instead of warning-and-degrading.
+
+    On a TPU backend complex128 is refused as well: the TPU compiler has no
+    c128 type and aborts the whole process on one, so the request fails
+    here, before anything is traced.
     """
     requested = np.dtype(dtype)
+    if requested == np.dtype(np.complex128) and on_tpu():
+        raise ValueError(
+            "complex128 is not supported on a TPU backend (the TPU compiler "
+            "has no c128 type); request dtype=complex64")
     with warnings.catch_warnings():
         warnings.simplefilter("ignore")
         effective = jnp.empty(0, dtype=dtype).dtype
@@ -96,7 +105,7 @@ class GLU:
         dense_tail: bool = True,
         dense_tail_density: float = 0.25,
         mode_override: Optional[str] = None,
-        interpret: bool = True,
+        interpret: Optional[bool] = None,
         plan_cache="default",
         layout: str = "auto",
         mesh=None,
@@ -134,6 +143,13 @@ class GLU:
         the fused program instead of hundreds of tiny scatter levels (no-op
         when no qualifying tail exists; ``dense_tail=False`` forces the
         strictly sparse schedule).
+
+        ``use_pallas``/``interpret``: route SEGMENTED/PANEL levels and the
+        dense tail through the Pallas kernels.  On a TPU they compile
+        through Mosaic and need float32 storage (``dtype=float32`` or
+        ``complex64``; a 64-bit request raises).  ``interpret=None``
+        resolves from the platform — interpret mode exactly off the TPU;
+        ``interpret=True`` on a TPU raises.
 
         ``layout``: device value-storage layout — ``"auto"`` (default)
         stores complex factors as split re/im planes (planar) whenever
@@ -195,7 +211,7 @@ class GLU:
         dense_tail: bool = True,
         dense_tail_density: float = 0.25,
         mode_override: Optional[str] = None,
-        interpret: bool = True,
+        interpret: Optional[bool] = None,
         layout: str = "auto",
         mesh=None,
         verify: str = "off",
@@ -247,7 +263,7 @@ class GLU:
         dense_tail: bool,
         dense_tail_density: float,
         mode_override: Optional[str],
-        interpret: bool,
+        interpret: Optional[bool],
         layout: str,
         mesh=None,
         verify: str = "off",

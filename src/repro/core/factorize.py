@@ -21,10 +21,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..distributed.collectives import psum_exact
+from ..kernels.backend import check_pallas_dtype, resolve_interpret
 from ..sparse.layout import pabs, pack_planes, pdiv, pmul, resolve_layout
 from .executor import resolve_executable_cache
 from .plan import (
@@ -402,7 +402,7 @@ def _build_dense_tail(plan: FactorizePlan, c_star: int, pad_key: int):
     return jnp.asarray(pos), jnp.asarray(eye), Np
 
 
-def _dense_tail_step_body(vals, pos, eye, *, interpret=True, use_pallas=False):
+def _dense_tail_step_body(vals, pos, eye, *, interpret=None, use_pallas=False):
     dense = vals.at[pos].get(mode="fill", fill_value=0.0)
     dense = dense + eye.astype(vals.dtype)
     if use_pallas:
@@ -437,7 +437,7 @@ _dense_tail_step_batched = partial(jax.jit, donate_argnums=(0,))(
     _dense_tail_step_batched_body)
 
 
-def _dense_tail_step_planar_body(vals, pos, eye, *, interpret=True,
+def _dense_tail_step_planar_body(vals, pos, eye, *, interpret=None,
                                  use_pallas=False):
     """Planar trailing block: gather (Np, Np, 2), factor the (2, Np, Np)
     plane pair (Pallas planar kernel or its XLA twin), scatter back.  The
@@ -665,12 +665,12 @@ def _build_factorize_runner(kinds, *, entry, batched, robust, interpret,
     in_specs = (bspec, P(), P(), P(), P())
     if robust:
         # per-matrix outputs stay batch-sharded; the psum'd global count is
-        # replicated (identical on every shard, so check_rep=False is safe).
+        # replicated (identical on every shard, so check_vma=False is safe).
         out_specs = (bspec, bspec, bspec, P())
     else:
         out_specs = bspec
-    mapped = shard_map(run, mesh=shard.mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+    mapped = jax.shard_map(run, mesh=shard.mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     return jax.jit(mapped, donate_argnums=donate)
 
 
@@ -699,7 +699,11 @@ class JaxFactorizer:
         same plan), an :class:`~repro.core.executor.ExecutableCache`, or
         ``None`` (private per-instance cache)
     use_pallas: route SEGMENTED/PANEL levels through the Pallas kernel
-        (interpret mode on CPU; compiled on real TPUs)
+        (compiled on a TPU, where it needs float32 storage; interpret mode
+        elsewhere)
+    interpret: run the Pallas kernels in interpret mode.  ``None`` (the
+        default) resolves from the platform: interpreted exactly when the
+        backend is not a TPU.  ``True`` on a TPU raises.
     dense_tail: switch-to-dense (on by default): when a trailing column
         block is dense enough, the hundreds of tiny levels covering it are
         replaced by ONE blocked dense-LU group inside the same fused
@@ -736,7 +740,7 @@ class JaxFactorizer:
         use_pallas: bool = False,
         mode_override: Optional[str] = None,
         disable_modes: tuple = (),
-        interpret: bool = True,
+        interpret: Optional[bool] = None,
         dense_tail: bool = True,
         dense_tail_density: float = 0.25,
         static_pivot: Optional[float] = None,
@@ -775,12 +779,12 @@ class JaxFactorizer:
                       "off the Pallas path")
         elif MODE_SEGMENTED in disable_modes and MODE_PANEL in disable_modes:
             reason = "disable_modes removes every Pallas-eligible mode"
-        elif interpret and jax.default_backend() == "tpu":
-            reason = ("interpret=True runs interpreter-mode kernels on a "
-                      "TPU backend")
+        if use_pallas:
+            check_pallas_dtype(self.storage_dtype)
         self.pallas_disabled_reason = reason
         self.use_pallas = use_pallas
-        self.interpret = interpret
+        # interpret mode exactly off the TPU; an explicit True on a TPU raises
+        self.interpret = resolve_interpret(interpret)
         self._a_scatter = jnp.asarray(plan.a_scatter, dtype=jnp.int32)
         self.nnz = plan.nnz
         # static pivot perturbation: |diag| < static_pivot * max|A| is bumped

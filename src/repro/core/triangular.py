@@ -35,7 +35,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..kernels.ops import masked_correction, spmv
@@ -238,8 +237,9 @@ def _build_trisolve_runner(kind: str, planar: bool = False, shard=None):
         # schedule is replicated; each shard's trisolve stays one dispatch.
         # Rows never interact, so the result is bit-identical to unsharded.
         bspec = shard.spec
-        fn = shard_map(fn, mesh=shard.mesh, in_specs=(bspec, bspec, P(), P()),
-                       out_specs=bspec, check_rep=False)
+        fn = jax.shard_map(fn, mesh=shard.mesh,
+                           in_specs=(bspec, bspec, P(), P()),
+                           out_specs=bspec, check_vma=False)
     return jax.jit(fn)
 
 

@@ -9,6 +9,11 @@ whose rank-B updates run on the MXU.
 Layout: in-place LU, L strictly below the diagonal (unit diagonal implied),
 U on/above.  No pivoting — the GLU flow guarantees numerically safe pivots
 via MC64 + diagonal dominance, same assumption as the paper.
+
+The tile lives in the output ref in VMEM and every step reads and writes it
+through static, (8, 128)-aligned ref slices.  A pivot, a row or a column
+selected by the loop index is extracted with a masked reduction, never by
+dynamically indexing a loaded vector (which Mosaic cannot lower).
 """
 from __future__ import annotations
 
@@ -17,80 +22,94 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .backend import resolve_interpret
 
 __all__ = ["dense_lu", "dense_lu_planar", "DEFAULT_BLOCK"]
 
 DEFAULT_BLOCK = 128
 
+_dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST)
 
-def _panel_factor(m, k0, B, N):
-    """Factor the B-wide panel [k0:, k0:k0+B] in place (unblocked, vectorised
-    over rows)."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, (N, 1), 0)
 
-    def col_step(jj, m):
+def _fori(n, body):
+    """``fori_loop`` over int32 indices: the index feeds Mosaic's i32 index
+    arithmetic even when JAX runs with 64-bit mode on."""
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(n), body, jnp.int32(0))
+
+
+def _pick(mask, x, axis):
+    """The one entry of ``x`` that ``mask`` selects along ``axis``."""
+    return jnp.sum(jnp.where(mask, x, 0.0), axis=axis, keepdims=True)
+
+
+def _vmem_params(n_tiles: int, N: int, dtype):
+    """Scoped-VMEM budget: input + output tiles plus temporaries of the same
+    order (the trailing product, panel and row views).  The default 16 MiB
+    scope is too small from N=768 up (f32 needs about 7 tiles there); v5e
+    has 128 MiB of VMEM."""
+    tile = N * N * jnp.dtype(dtype).itemsize
+    need = 8 * n_tiles * tile + (4 << 20)
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=int(min(max(need, 16 << 20), 100 << 20)))
+
+
+def _panel_factor(o_ref, k0, B, N):
+    """Factor the B-wide panel [k0:, k0:k0+B] in place, one column a step."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (N - k0, 1), 0) + k0
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
+
+    def col_step(jj, carry):
         j = k0 + jj
-        piv = m[j, j]
-        col = m[:, j][:, None]                       # (N,1)
-        lcol = jnp.where(rows > j, col / piv, col)
-        m = jax.lax.dynamic_update_slice(m, lcol, (0, j))
+        p = o_ref[k0:, k0:k0 + B]
+        prow = _pick(rows == j, p, 0)                       # (1, B)
+        piv = _pick(cols == jj, prow, 1)                    # (1, 1)
+        col = _pick(cols == jj, p, 1)                       # (H, 1)
+        below = rows > j
+        lcol = jnp.where(below, col / piv, col)
         # rank-1 update restricted to the remaining panel columns
-        row = m[j, :][None, :]                       # (1,N)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (1, N), 1)
-        row_m = jnp.where((cols > j) & (cols < k0 + B), row, 0.0)
-        l_m = jnp.where(rows > j, lcol, 0.0)
-        return m - l_m @ row_m
+        lm = jnp.where(below, lcol, 0.0)
+        um = jnp.where(cols > jj, prow, 0.0)
+        o_ref[k0:, k0:k0 + B] = jnp.where(cols == jj, lcol, p) - lm * um
+        return carry
 
-    return jax.lax.fori_loop(0, B, col_step, m)
-
-
-def _trsm_rows(m, k0, B, N):
-    """Rows k0:k0+B of the trailing columns: U12 = L11^{-1} A12 (unit lower).
-
-    Forward substitution down the B rows of the diagonal block.
-    """
-    cols = jax.lax.broadcasted_iota(jnp.int32, (1, N), 1)
-
-    def row_step(ii, m):
-        i = k0 + ii
-        # row_i -= sum_{t<i, t>=k0} L(i,t) * row_t   (already-final rows)
-        acc = jnp.zeros((1, N), m.dtype)
-
-        def inner(tt, acc):
-            t = k0 + tt
-            lit = m[i, t]
-            return acc + lit * jnp.where(cols >= k0 + B, m[t, :][None, :], 0.0)
-
-        acc = jax.lax.fori_loop(0, ii, inner, acc)
-        new_row = m[i, :][None, :] - acc
-        new_row = jnp.where(cols >= k0 + B, new_row, m[i, :][None, :])
-        return jax.lax.dynamic_update_slice(m, new_row, (i, 0))
-
-    return jax.lax.fori_loop(0, B, row_step, m)
+    _fori(B, col_step)
 
 
-def _lu_kernel(a_ref, out_ref, *, N: int, B: int):
-    m = a_ref[...]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (N, 1), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (1, N), 1)
-    nblk = N // B
-    for kb in range(nblk):
-        k0 = kb * B
-        m = _panel_factor(m, k0, B, N)
-        if kb < nblk - 1:
-            m = _trsm_rows(m, k0, B, N)
+def _trsm_rows(o_ref, k0, B, N):
+    """Rows k0:k0+B of the trailing columns: U12 = L11^{-1} A12 (unit lower),
+    column-oriented forward substitution down the B rows."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
+    l11 = o_ref[k0:k0 + B, k0:k0 + B]
+
+    def row_step(ii, carry):
+        x = o_ref[k0:k0 + B, k0 + B:]
+        xrow = _pick(rows == ii, x, 0)                      # final row ii
+        lcol = _pick(cols == ii, l11, 1)                    # L11[:, ii]
+        o_ref[k0:k0 + B, k0 + B:] = x - jnp.where(rows > ii, lcol, 0.0) * xrow
+        return carry
+
+    _fori(B, row_step)
+
+
+def _lu_kernel(a_ref, o_ref, *, N: int, B: int):
+    o_ref[...] = a_ref[...]
+    for k0 in range(0, N, B):
+        _panel_factor(o_ref, k0, B, N)
+        if k0 + B < N:
+            _trsm_rows(o_ref, k0, B, N)
             # trailing update A22 -= L21 @ U12 on the MXU
-            lmask = (rows >= k0 + B) & (cols >= k0) & (cols < k0 + B)
-            umask = (rows >= k0) & (rows < k0 + B) & (cols >= k0 + B)
-            L21 = jnp.where(lmask, m, 0.0)
-            U12 = jnp.where(umask, m, 0.0)
-            m = m - jnp.dot(L21, U12, preferred_element_type=m.dtype)
-    out_ref[...] = m
+            k1 = k0 + B
+            o_ref[k1:, k1:] = o_ref[k1:, k1:] - _dot(o_ref[k1:, k0:k1],
+                                                     o_ref[k0:k1, k1:])
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def dense_lu(a, *, block: int = DEFAULT_BLOCK, interpret: bool = True):
+def dense_lu(a, *, block: int = DEFAULT_BLOCK, interpret=None):
     """In-place-layout unpivoted LU of a dense (N, N) tile."""
+    interpret = resolve_interpret(interpret)
     N = a.shape[0]
     B = min(block, N)
     assert N % B == 0, (N, B)
@@ -98,6 +117,7 @@ def dense_lu(a, *, block: int = DEFAULT_BLOCK, interpret: bool = True):
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((N, N), a.dtype),
+        compiler_params=_vmem_params(1, N, a.dtype),
         interpret=interpret,
     )(a)
 
@@ -110,96 +130,74 @@ def dense_lu(a, *, block: int = DEFAULT_BLOCK, interpret: bool = True):
 # no complex operands).
 # --------------------------------------------------------------------------
 
-def _cmul(ar, ai, br, bi):
-    return ar * br - ai * bi, ar * bi + ai * br
+def _panel_factor_planar(o_ref, k0, B, N):
+    """Planar twin of :func:`_panel_factor` on the (2, N, N) plane pair."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (N - k0, 1), 0) + k0
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
 
-
-def _panel_factor_planar(mr, mi, k0, B, N):
-    """Planar twin of :func:`_panel_factor` on (N, N) re/im planes."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, (N, 1), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (1, N), 1)
-
-    def col_step(jj, m):
-        mr, mi = m
+    def col_step(jj, carry):
         j = k0 + jj
-        pr, pi = mr[j, j], mi[j, j]
-        inv = 1.0 / (pr * pr + pi * pi)
-        cr = mr[:, j][:, None]
-        ci = mi[:, j][:, None]
-        qr = (cr * pr + ci * pi) * inv
-        qi = (ci * pr - cr * pi) * inv
-        lr = jnp.where(rows > j, qr, cr)
-        li = jnp.where(rows > j, qi, ci)
-        mr = jax.lax.dynamic_update_slice(mr, lr, (0, j))
-        mi = jax.lax.dynamic_update_slice(mi, li, (0, j))
+        pr, pi = o_ref[0, k0:, k0:k0 + B], o_ref[1, k0:, k0:k0 + B]
+        rr, ri = _pick(rows == j, pr, 0), _pick(rows == j, pi, 0)
+        vr, vi = _pick(cols == jj, rr, 1), _pick(cols == jj, ri, 1)
+        inv = 1.0 / (vr * vr + vi * vi)
+        cr, ci = _pick(cols == jj, pr, 1), _pick(cols == jj, pi, 1)
+        below = rows > j
+        lr = jnp.where(below, (cr * vr + ci * vi) * inv, cr)
+        li = jnp.where(below, (ci * vr - cr * vi) * inv, ci)
         # rank-1 update restricted to the remaining panel columns
-        row_mask = (cols > j) & (cols < k0 + B)
-        rr = jnp.where(row_mask, mr[j, :][None, :], 0.0)
-        ri = jnp.where(row_mask, mi[j, :][None, :], 0.0)
-        lmr = jnp.where(rows > j, lr, 0.0)
-        lmi = jnp.where(rows > j, li, 0.0)
-        ur, ui = _cmul(lmr, lmi, rr, ri)
-        return mr - ur, mi - ui
+        lmr, lmi = jnp.where(below, lr, 0.0), jnp.where(below, li, 0.0)
+        umr, umi = jnp.where(cols > jj, rr, 0.0), jnp.where(cols > jj, ri, 0.0)
+        here = cols == jj
+        o_ref[0, k0:, k0:k0 + B] = (jnp.where(here, lr, pr)
+                                    - (lmr * umr - lmi * umi))
+        o_ref[1, k0:, k0:k0 + B] = (jnp.where(here, li, pi)
+                                    - (lmr * umi + lmi * umr))
+        return carry
 
-    return jax.lax.fori_loop(0, B, col_step, (mr, mi))
+    _fori(B, col_step)
 
 
-def _trsm_rows_planar(mr, mi, k0, B, N):
+def _trsm_rows_planar(o_ref, k0, B, N):
     """Planar twin of :func:`_trsm_rows`."""
-    cols = jax.lax.broadcasted_iota(jnp.int32, (1, N), 1)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
+    l11r = o_ref[0, k0:k0 + B, k0:k0 + B]
+    l11i = o_ref[1, k0:k0 + B, k0:k0 + B]
 
-    def row_step(ii, m):
-        mr, mi = m
-        i = k0 + ii
-        accr = jnp.zeros((1, N), mr.dtype)
-        acci = jnp.zeros((1, N), mi.dtype)
+    def row_step(ii, carry):
+        xr, xi = o_ref[0, k0:k0 + B, k0 + B:], o_ref[1, k0:k0 + B, k0 + B:]
+        tr, ti = _pick(rows == ii, xr, 0), _pick(rows == ii, xi, 0)
+        below = rows > ii
+        lr = jnp.where(below, _pick(cols == ii, l11r, 1), 0.0)
+        li = jnp.where(below, _pick(cols == ii, l11i, 1), 0.0)
+        o_ref[0, k0:k0 + B, k0 + B:] = xr - (lr * tr - li * ti)
+        o_ref[1, k0:k0 + B, k0 + B:] = xi - (lr * ti + li * tr)
+        return carry
 
-        def inner(tt, acc):
-            accr, acci = acc
-            t = k0 + tt
-            lr, li = mr[i, t], mi[i, t]
-            tr = jnp.where(cols >= k0 + B, mr[t, :][None, :], 0.0)
-            ti = jnp.where(cols >= k0 + B, mi[t, :][None, :], 0.0)
-            return accr + (lr * tr - li * ti), acci + (lr * ti + li * tr)
-
-        accr, acci = jax.lax.fori_loop(0, ii, inner, (accr, acci))
-        nr = mr[i, :][None, :] - accr
-        ni = mi[i, :][None, :] - acci
-        nr = jnp.where(cols >= k0 + B, nr, mr[i, :][None, :])
-        ni = jnp.where(cols >= k0 + B, ni, mi[i, :][None, :])
-        return (jax.lax.dynamic_update_slice(mr, nr, (i, 0)),
-                jax.lax.dynamic_update_slice(mi, ni, (i, 0)))
-
-    return jax.lax.fori_loop(0, B, row_step, (mr, mi))
+    _fori(B, row_step)
 
 
-def _lu_kernel_planar(a_ref, out_ref, *, N: int, B: int):
-    m = a_ref[...]                               # (2, N, N)
-    mr, mi = m[0], m[1]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (N, 1), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (1, N), 1)
-    nblk = N // B
-    for kb in range(nblk):
-        k0 = kb * B
-        mr, mi = _panel_factor_planar(mr, mi, k0, B, N)
-        if kb < nblk - 1:
-            mr, mi = _trsm_rows_planar(mr, mi, k0, B, N)
+def _lu_kernel_planar(a_ref, o_ref, *, N: int, B: int):
+    o_ref[...] = a_ref[...]
+    for k0 in range(0, N, B):
+        _panel_factor_planar(o_ref, k0, B, N)
+        if k0 + B < N:
+            _trsm_rows_planar(o_ref, k0, B, N)
             # trailing update A22 -= L21 @ U12: 4 real matmuls on the MXU
-            lmask = (rows >= k0 + B) & (cols >= k0) & (cols < k0 + B)
-            umask = (rows >= k0) & (rows < k0 + B) & (cols >= k0 + B)
-            L21r = jnp.where(lmask, mr, 0.0)
-            L21i = jnp.where(lmask, mi, 0.0)
-            U12r = jnp.where(umask, mr, 0.0)
-            U12i = jnp.where(umask, mi, 0.0)
-            dot = functools.partial(jnp.dot, preferred_element_type=mr.dtype)
-            mr = mr - (dot(L21r, U12r) - dot(L21i, U12i))
-            mi = mi - (dot(L21r, U12i) + dot(L21i, U12r))
-    out_ref[...] = jnp.stack([mr, mi])
+            k1 = k0 + B
+            l21r, l21i = o_ref[0, k1:, k0:k1], o_ref[1, k1:, k0:k1]
+            u12r, u12i = o_ref[0, k0:k1, k1:], o_ref[1, k0:k1, k1:]
+            o_ref[0, k1:, k1:] = o_ref[0, k1:, k1:] - (
+                _dot(l21r, u12r) - _dot(l21i, u12i))
+            o_ref[1, k1:, k1:] = o_ref[1, k1:, k1:] - (
+                _dot(l21r, u12i) + _dot(l21i, u12r))
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def dense_lu_planar(a, *, block: int = DEFAULT_BLOCK, interpret: bool = True):
+def dense_lu_planar(a, *, block: int = DEFAULT_BLOCK, interpret=None):
     """Unpivoted LU of a complex (N, N) tile stored as (2, N, N) planes."""
+    interpret = resolve_interpret(interpret)
     N = a.shape[-1]
     B = min(block, N)
     assert a.shape == (2, N, N) and N % B == 0, (a.shape, B)
@@ -207,5 +205,6 @@ def dense_lu_planar(a, *, block: int = DEFAULT_BLOCK, interpret: bool = True):
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((2, N, N), a.dtype),
+        compiler_params=_vmem_params(2, N, a.dtype),
         interpret=interpret,
     )(a)

@@ -8,12 +8,14 @@ VMEM with a one-hot matmul — the MXU performs the scatter-add.
 
 Geometry (chosen at plan time per level — the TPU analogue of the paper's
 three adaptive modes):
-  D  — destination columns processed by the grid's first axis
+  D  — destination columns, SUB=8 of them per grid step (one sublane tile;
+       the wrapper pads D up to a multiple of SUB)
   R  — padded updates per destination column (multiple of RC=256)
   C  — padded destination column length, split into CB=512 blocks
-Type B levels compile with large D / small R,C; type C levels with small D /
-large R,C (panel).  Type A levels bypass this kernel entirely (flat XLA
-scatter-add is optimal there).
+Every block is then (8, 128·k), which the TPU's (8, 128) tiling rule
+accepts.  Type B levels compile with large D / small R,C; type C levels with
+small D / large R,C (panel).  Type A levels bypass this kernel entirely
+(flat XLA scatter-add is optimal there).
 """
 from __future__ import annotations
 
@@ -23,49 +25,77 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["segmented_accumulate", "RC", "CB"]
+from .backend import resolve_interpret
+
+__all__ = ["segmented_accumulate", "RC", "CB", "SUB"]
 
 RC = 256   # contribution chunk (MXU contraction dim)
 CB = 512   # destination column block (MXU output dim)
+SUB = 8    # destination columns per grid step (f32 sublane tile)
+
+# contribution rows (1, RC) against the (CB, RC) one-hot: contract both
+# last dims, the "NT" matmul form the MXU takes directly
+_NT = (((1,), (1,)), ((), ()))
 
 
-def _kernel(cv_ref, cb_ref, dl_ref, out_ref, *, R: int, cb_size: int):
-    """One (destination column, column block) cell."""
-    blk = pl.program_id(1)
-    base = blk * cb_size
+def _kernel(cv_ref, cb_ref, dl_ref, out_ref, *, n_rc: int, cb_size: int):
+    """One (8 destination columns, column block) cell."""
+    base = pl.program_id(1) * cb_size
     dtype = cv_ref.dtype
-    acc = jnp.zeros((1, cb_size), dtype=dtype)
-    col_ids = jax.lax.broadcasted_iota(jnp.int32, (RC, cb_size), 1) + base
-    for rc in range(R // RC):
-        dl = dl_ref[0, rc * RC : (rc + 1) * RC]            # (RC,) int32
-        onehot = (dl[:, None] == col_ids).astype(dtype)     # (RC, CB)
-        contrib = cb_ref[0, rc * RC : (rc + 1) * RC][None, :]
-        acc = acc + jnp.dot(contrib, onehot, preferred_element_type=dtype)
-    out_ref[...] = cv_ref[...] + acc
+    col_ids = jax.lax.broadcasted_iota(jnp.int32, (cb_size, RC), 0) + base
+    for d in range(SUB):
+        def chunk(rc, acc, d=d):
+            off = pl.multiple_of(rc * RC, RC)
+            dl = dl_ref[pl.ds(d, 1), pl.ds(off, RC)]             # (1, RC)
+            contrib = cb_ref[pl.ds(d, 1), pl.ds(off, RC)]        # (1, RC)
+            onehot = (dl == col_ids).astype(dtype)               # (CB, RC)
+            return acc + jax.lax.dot_general(
+                contrib, onehot, _NT, precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=dtype)
+        # int32 bounds: the loop index feeds Mosaic's i32 index arithmetic
+        # even when JAX runs with 64-bit mode on
+        acc = jax.lax.fori_loop(jnp.int32(0), jnp.int32(n_rc), chunk,
+                                jnp.zeros((1, cb_size), dtype=dtype))
+        out_ref[pl.ds(d, 1), :] = cv_ref[pl.ds(d, 1), :] + acc
+
+
+def _row_block(d, b):
+    # an int32 zero: a Python 0 would turn i64 under 64-bit mode, which the
+    # Mosaic index map cannot return
+    return d, jnp.int32(0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def segmented_accumulate(col_vals, contribs, didx_local, *, interpret: bool = True):
+def segmented_accumulate(col_vals, contribs, didx_local, *, interpret=None):
     """col_vals (D,C) += scatter(contribs (D,R) at didx_local (D,R)).
 
     Padding: contribs 0-padded; didx_local padded with >= C (never matches).
-    C must be a multiple of CB or < CB (then one block); R a multiple of RC.
+    C must be a multiple of CB or < CB (then one block, a multiple of 128);
+    R a multiple of RC.  ``interpret=None`` runs compiled on a TPU and
+    interpreted elsewhere (see :mod:`repro.kernels.backend`).
     """
+    interpret = resolve_interpret(interpret)
     D, C = col_vals.shape
     _, R = contribs.shape
     cb_size = min(C, CB)
     assert C % cb_size == 0 and R % RC == 0, (C, R)
-    n_cb = C // cb_size
-    kernel = functools.partial(_kernel, R=R, cb_size=cb_size)
-    return pl.pallas_call(
+    pad = -D % SUB
+    if pad:
+        col_vals = jnp.pad(col_vals, ((0, pad), (0, 0)))
+        contribs = jnp.pad(contribs, ((0, pad), (0, 0)))
+        didx_local = jnp.pad(didx_local, ((0, pad), (0, 0)),
+                             constant_values=C)
+    kernel = functools.partial(_kernel, n_rc=R // RC, cb_size=cb_size)
+    out = pl.pallas_call(
         kernel,
-        grid=(D, n_cb),
+        grid=((D + pad) // SUB, C // cb_size),
         in_specs=[
-            pl.BlockSpec((1, cb_size), lambda d, b: (d, b)),
-            pl.BlockSpec((1, R), lambda d, b: (d, 0)),
-            pl.BlockSpec((1, R), lambda d, b: (d, 0)),
+            pl.BlockSpec((SUB, cb_size), lambda d, b: (d, b)),
+            pl.BlockSpec((SUB, R), _row_block),
+            pl.BlockSpec((SUB, R), _row_block),
         ],
-        out_specs=pl.BlockSpec((1, cb_size), lambda d, b: (d, b)),
-        out_shape=jax.ShapeDtypeStruct((D, C), col_vals.dtype),
+        out_specs=pl.BlockSpec((SUB, cb_size), lambda d, b: (d, b)),
+        out_shape=jax.ShapeDtypeStruct((D + pad, C), col_vals.dtype),
         interpret=interpret,
     )(col_vals, contribs, didx_local)
+    return out[:D] if pad else out

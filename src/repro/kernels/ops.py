@@ -54,7 +54,7 @@ def level_update_body(
     didx_local,
     col_positions,
     *,
-    interpret: bool = True,
+    interpret=None,
 ):
     """One GLU level via the segmented Pallas kernel.
 
@@ -92,7 +92,7 @@ def level_update_batched_body(
     didx_local,
     col_positions,
     *,
-    interpret: bool = True,
+    interpret=None,
 ):
     """One GLU level for a whole batch of matrices sharing the plan.
 
@@ -145,7 +145,7 @@ def level_update_planar_body(
     didx_local,
     col_positions,
     *,
-    interpret: bool = True,
+    interpret=None,
 ):
     """Planar twin of :func:`level_update_body`: ``vals`` is (nnz, 2)."""
     D, R = lidx2d.shape
@@ -179,7 +179,7 @@ def level_update_planar_batched_body(
     didx_local,
     col_positions,
     *,
-    interpret: bool = True,
+    interpret=None,
 ):
     """Planar batched twin: ``vals`` is (B, nnz, 2); batch AND plane axes
     fold into the kernel grid — ONE launch with grid (B*2*D, C//CB)."""

@@ -7,9 +7,11 @@ from __future__ import annotations
 
 import argparse
 
+import jax
 import numpy as np
 
 from ..circuit import rc_grid_circuit, transient
+from ..compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -23,6 +25,9 @@ def main(argv=None):
     ap.add_argument("--pallas", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    # the circuit is simulated in float64, which JAX runs only in 64-bit mode
+    jax.config.update("jax_enable_x64", True)
+    enable_compile_cache()
 
     ckt = rc_grid_circuit(args.nx, args.ny, with_diodes=not args.no_diodes,
                           seed=args.seed)

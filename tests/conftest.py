@@ -17,12 +17,10 @@ _TESTS_SINCE_CLEAR = 0
 
 @pytest.fixture(autouse=True)
 def _bounded_xla_code_accumulation():
-    """Work around an XLA-CPU crash under long single-process suites: after
-    a few hundred distinct jit compilations the NEXT LLVM compile segfaults
-    inside ``backend_compile`` (observed at a stable ~190-test mark
-    regardless of which test gets there, jaxlib 0.4.36).  Dropping the
-    executable caches periodically keeps cumulative emitted code bounded;
-    the cost is a handful of recompiles per suite run."""
+    """Keep the compiled code one test process accumulates bounded: every
+    plan compiles its own whole-schedule programs, and a long suite in one
+    process otherwise piles up hundreds of CPU executables.  Dropping the
+    executable caches every 64 tests costs a handful of recompiles."""
     global _TESTS_SINCE_CLEAR
     yield
     _TESTS_SINCE_CLEAR += 1

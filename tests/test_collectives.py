@@ -4,7 +4,6 @@ compressed/exact psum helpers (single-device mesh in-process; the real
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import (
@@ -42,8 +41,8 @@ def test_quantize_zero_vector():
 def test_compressed_psum_single_shard_is_fake_quantize():
     mesh = jax.make_mesh((1,), ("data",))
     x = jnp.asarray(np.linspace(-1.0, 1.0, 64, dtype=np.float32))
-    out = shard_map(lambda v: compressed_psum(v, "data"), mesh=mesh,
-                    in_specs=(P(),), out_specs=P(), check_rep=False)(x)
+    out = jax.shard_map(lambda v: compressed_psum(v, "data"), mesh=mesh,
+                        in_specs=(P(),), out_specs=P(), check_vma=False)(x)
     q, scale = quantize_int8(x)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(dequantize_int8(q, scale)),
@@ -54,8 +53,8 @@ def test_compressed_psum_tree_structure_preserved():
     mesh = jax.make_mesh((1,), ("data",))
     tree = {"w": jnp.ones((4, 4), jnp.float32),
             "b": jnp.full((4,), -2.0, jnp.float32)}
-    out = shard_map(lambda t: compressed_psum(t, "data"), mesh=mesh,
-                    in_specs=(P(),), out_specs=P(), check_rep=False)(tree)
+    out = jax.shard_map(lambda t: compressed_psum(t, "data"), mesh=mesh,
+                        in_specs=(P(),), out_specs=P(), check_vma=False)(tree)
     assert set(out) == {"w", "b"}
     np.testing.assert_allclose(np.asarray(out["w"]), 1.0, atol=1e-2)
     np.testing.assert_allclose(np.asarray(out["b"]), -2.0, atol=1e-1)
@@ -65,8 +64,8 @@ def test_psum_exact_integers_stay_exact():
     mesh = jax.make_mesh((1,), ("data",))
     tree = {"bumps": jnp.asarray(3, jnp.int64),
             "counts": jnp.asarray([1, 2, 3], jnp.int32)}
-    out = shard_map(lambda t: psum_exact(t, "data"), mesh=mesh,
-                    in_specs=(P(),), out_specs=P(), check_rep=False)(tree)
+    out = jax.shard_map(lambda t: psum_exact(t, "data"), mesh=mesh,
+                        in_specs=(P(),), out_specs=P(), check_vma=False)(tree)
     assert int(out["bumps"]) == 3
     assert out["bumps"].dtype == jnp.int64
     np.testing.assert_array_equal(np.asarray(out["counts"]), [1, 2, 3])

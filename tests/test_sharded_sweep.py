@@ -73,7 +73,6 @@ import sys
 sys.path.insert(0, "src")
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.core import GLU
 from repro.distributed import make_scenario_sharding, make_sweep_mesh, psum_exact
@@ -101,9 +100,9 @@ s8 = make_scenario_sharding(mesh8)
 assert s8.n_shards == 8 and s8.descriptor != s4.descriptor
 
 # psum_exact really reduces across all 8 shards, exactly
-tot = shard_map(lambda v: psum_exact(jnp.sum(v), "data"), mesh=mesh8,
-                in_specs=(P("data"),), out_specs=P(), check_rep=False)(
-                    jnp.arange(8, dtype=jnp.int64))
+tot = jax.shard_map(lambda v: psum_exact(jnp.sum(v), "data"), mesh=mesh8,
+                    in_specs=(P("data"),), out_specs=P(), check_vma=False)(
+                        jnp.arange(8, dtype=jnp.int64))
 assert int(tot) == 28, int(tot)
 
 # mode matrix: sharded == single-device batched, bit for bit

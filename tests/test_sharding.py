@@ -29,13 +29,14 @@ import sys
 sys.path.insert(0, "src")
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.distributed.sharding import (axis_env, make_rules, tree_shardings,
                                         logical_constraint, sharding_for_spec)
 from repro.configs import get_config
 from repro.models.model import param_specs
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto, AxisType.Auto))
 cfg = get_config("qwen2.5-3b").reduced()
 rules = make_rules(cfg)
 
