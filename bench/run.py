@@ -1,0 +1,346 @@
+#!/usr/bin/env python3
+"""Run one benchmark cell once.
+
+    python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+Everything a cell is made of is found by name: the cell in
+``BENCHMARK.json``, its configuration in the file that entry names, its
+traffic mix in ``bench/mixes/<traffic>.json`` and each per-layer metric's
+reader in ``bench/metrics/<metric>.py``.  Adding any of them is adding files
+and entries; nothing here names a cell.
+
+A run builds the configuration's matrix, draws the cell's values and
+right-hand sides from ``--seed``, constructs ``GLU`` with no plan cache (so
+every run pays the plan build, as a new netlist does), warms up the cell's
+own shapes, and measures for ``--seconds``.  ``setup_s`` runs from the start
+of this script to the first timed step.  With ``--trace 1`` a short window
+of ``trace_steps`` calls runs under the profiler and the per-layer metrics
+are read from it instead of the end-to-end ones.  Then the reference
+(``reference.py``) checks what the window returned.  The last line of
+standard output is one JSON object; the numbers compared, each with its
+limit, are the last lines of standard error and the last key of that object.
+
+Without a TPU, or with fewer chips than the cell asks for, it exits 2 and
+prints no result.  ``--value-dtype float32`` runs the same cell with the
+program's float32 value path: the control, which has to come out not
+correct.
+"""
+from __future__ import annotations
+
+import time
+
+T0 = time.perf_counter()
+
+import argparse  # noqa: E402
+import importlib.util  # noqa: E402
+import json  # noqa: E402
+import math  # noqa: E402
+import os  # noqa: E402
+import statistics  # noqa: E402
+import sys  # noqa: E402
+import tempfile  # noqa: E402
+from pathlib import Path  # noqa: E402
+
+BENCH = Path(__file__).resolve().parent
+ROOT = BENCH.parent
+os.environ.setdefault("JAX_ENABLE_X64", "1")
+sys.path.insert(0, str(BENCH))
+sys.path.insert(1, str(ROOT / "src"))
+
+import gen  # noqa: E402
+import reference  # noqa: E402
+import trace as trace_mod  # noqa: E402
+from traffic import Traffic  # noqa: E402
+
+if Path(trace_mod.__file__).parent != BENCH:    # the standard library's trace
+    raise ImportError("bench/trace.py is shadowed by an imported 'trace'")
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+class NoChip(RuntimeError):
+    """The machine lacks the accelerator the cell asks for."""
+
+
+class CompileClock:
+    """Sums backend compile seconds (persistent-cache reads included)."""
+
+    def __init__(self):
+        self.seconds = 0.0
+        self.programs = 0
+
+    def __call__(self, event: str, duration: float, **_) -> None:
+        if event == COMPILE_EVENT:
+            self.seconds += duration
+            self.programs += 1
+
+
+_CLOCK = None
+
+
+def compile_clock() -> CompileClock:
+    global _CLOCK
+    if _CLOCK is None:
+        import jax
+
+        _CLOCK = CompileClock()
+        jax.monitoring.register_event_duration_secs_listener(_CLOCK)
+    return _CLOCK
+
+
+# -- finding things by name -------------------------------------------------
+
+def load_spec(root: Path = ROOT) -> dict:
+    return json.loads((root / "BENCHMARK.json").read_text())
+
+
+def cell(spec: dict, workload: str) -> dict:
+    for w in spec["workloads"]:
+        if w["name"] == workload:
+            return w
+    raise KeyError(f"no workload {workload!r} in BENCHMARK.json")
+
+
+def load_config(spec: dict, name: str, root: Path = ROOT) -> dict:
+    for c in spec["configs"]:
+        if c["name"] == name:
+            return json.loads((root / c["file"]).read_text())
+    raise KeyError(f"no config {name!r} in BENCHMARK.json")
+
+
+def load_mix(name: str, bench: Path = BENCH) -> dict:
+    return json.loads((bench / "mixes" / f"{name}.json").read_text())
+
+
+def load_reader(name: str, bench: Path = BENCH):
+    path = bench / "metrics" / f"{name}.py"
+    mod_spec = importlib.util.spec_from_file_location(
+        "bench_metric_" + name.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod.read
+
+
+def metrics_of(entries, workload: str):
+    return [m for m in entries
+            if "workloads" not in m or workload in m["workloads"]]
+
+
+# -- one run --------------------------------------------------------------------
+
+def devices_for(chips: int, require_tpu: bool):
+    import jax
+
+    devs = jax.devices()
+    if require_tpu and devs[0].platform != "tpu":
+        raise NoChip(f"no TPU: JAX backend is {devs[0].platform!r}")
+    if len(devs) < chips:
+        raise NoChip(f"the cell asks for {chips} chips, JAX sees {len(devs)}")
+    return devs
+
+
+def build_glu(n, pattern, data, dtype, plan_cache):
+    import jax.numpy as jnp
+
+    from repro.core import GLU
+    from repro.sparse.csc import CSC
+
+    indptr, indices = pattern
+    A = CSC(n, indptr, indices, data)
+    return GLU(A, dtype=jnp.dtype(dtype), plan_cache=plan_cache)
+
+
+def traced_window(traffic, glu, max_steps: int, seconds: float,
+                  tdir: str) -> int:
+    """Run up to ``max_steps`` calls (or ``seconds``) of the traffic under
+    the profiler, writing the trace into ``tdir``; returns the calls run."""
+    import jax
+
+    jax.profiler.start_trace(tdir)
+    tw = time.perf_counter()
+    with jax.profiler.TraceAnnotation(trace_mod.WINDOW):
+        steps = 0
+        while True:
+            traffic.step(glu, steps, traced=True)
+            steps += 1
+            if steps >= max_steps or time.perf_counter() - tw >= seconds:
+                break
+    jax.profiler.stop_trace()
+    return steps
+
+
+def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
+             spec: dict | None = None, config: dict | None = None,
+             value_dtype: str | None = None, require_tpu: bool = True,
+             plan_cache=None, t0: float | None = None,
+             root: Path = ROOT) -> dict:
+    """One run of ``workload``; returns the result object.
+
+    ``config`` replaces the configuration file's contents (tests use it to
+    run a cell at a size the CPU holds), ``value_dtype`` the configuration's
+    value dtype (the control), ``plan_cache`` the ``GLU`` plan cache
+    (``None``: every run builds its plan), ``root`` the checkout whose
+    ``BENCHMARK.json`` and ``bench/`` files name the cell.
+    """
+    t0 = T0 if t0 is None else t0
+    spec = spec or load_spec(root)
+    w = cell(spec, workload)
+    devs = devices_for(int(w["chips"]), require_tpu)
+
+    import jax
+
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    clock = compile_clock()
+    compile0 = clock.seconds
+
+    cfg = config or load_config(spec, w["config"], root)
+    mix = load_mix(w["traffic"], root / "bench")
+    n, (indptr, indices, data), net = gen.make_matrix(cfg)
+    pattern = (indptr, indices)
+    traffic = Traffic(mix, net, seed)
+    dtype = value_dtype or cfg["value_dtype"]
+    glu = build_glu(n, pattern, data, dtype, plan_cache)
+    plan_build_s = glu.symbolic_plan.build_seconds["total"]
+    for i in range(int(mix["warmup"])):       # as the window will call it
+        traffic.step(glu, i, traced=trace)
+    traffic.outputs.clear()
+    traffic.refine_iters.clear()
+    compile_s = clock.seconds - compile0
+    setup_s = time.perf_counter() - t0
+
+    times = []
+    compiles_before = clock.programs
+    used = devs[: int(w["chips"])]
+    if trace:
+        with tempfile.TemporaryDirectory(prefix="bench_trace_") as tdir:
+            steps = traced_window(traffic, glu, int(mix["trace_steps"]),
+                                  seconds, tdir)
+            spans, devices = trace_mod.read(trace_mod.find_xplane(tdir),
+                                            [d.id for d in used])
+        summary = trace_mod.reduce(spans, devices)
+    else:
+        tw = time.perf_counter()
+        steps = 0
+        while True:
+            ts = time.perf_counter()
+            traffic.step(glu, steps)
+            te = time.perf_counter()
+            times.append(te - ts)
+            steps += 1
+            if te - tw >= seconds:
+                break
+        window_s = te - tw
+    window_compiles = clock.programs - compiles_before
+
+    peak = 0
+    for d in used:
+        stats = d.memory_stats() or {}
+        peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
+    del glu
+
+    answers = traffic.answers()
+    checks, failed = reference.check(
+        n, pattern, answers, cfg["limits"], int(mix["check_sample"]), seed)
+    correct = (failed == 0 and len(answers) > 0
+               and all(c["value"] <= c["limit"] for c in checks.values()))
+
+    if trace:
+        ctx = {"plan_build_s": plan_build_s, "compile_s": compile_s,
+               "kind": traffic.kind, "steps": steps,
+               "refine_iters": traffic.refine_iters, "trace": summary}
+        metrics = {}
+        for m in metrics_of(spec["per_layer"], workload):
+            v = load_reader(m["name"], root / "bench")(ctx)
+            if v is not None:
+                metrics[m["name"]] = {"value": v, "unit": m["unit"]}
+    else:
+        calls_per_s = steps / window_s
+        e2e = {
+            "setup_s": setup_s,
+            "newton_step_ms": 1e3 * window_s / steps,
+            "newton_step_p95_ms": 1e3 * statistics.quantiles(
+                times, n=20, method="inclusive")[18] if steps > 1
+            else 1e3 * times[0],
+            "sweep_mps": calls_per_s * traffic.matrices_per_call(),
+        }
+        metrics = {}
+        for m in metrics_of(spec["end_to_end"], workload):
+            if m["name"] not in e2e:
+                raise KeyError(f"no reading for end-to-end metric "
+                               f"{m['name']!r}")
+            metrics[m["name"]] = {"value": e2e[m["name"]], "unit": m["unit"]}
+
+    device = {"platform": used[0].platform, "kind": used[0].device_kind,
+              "count": len(used), "memory_peak_bytes": peak}
+    info = {"steps": steps, "answers": len(answers),
+            "window_compiles": window_compiles,
+            "plan_build_s": plan_build_s, "compile_s": compile_s,
+            "value_dtype": str(dtype)}
+    result = {"correct": bool(correct), "attempted": len(answers),
+              "failed": int(failed), "metrics": metrics, "device": device}
+    if trace:
+        device["busy_s"] = summary["busy_s"]
+        device["window_s"] = summary["window_s"]
+        info["busy_s_by_device"] = summary["busy_s_by_device"]
+        info["span_device_s"] = summary["span_device_s"]
+        result["breakdown"] = {"device_ops": summary["device_ops"],
+                               "idle_gaps": summary["idle_gaps"]}
+    else:
+        info["window_s"] = window_s
+        info["step_ms"] = [1e3 * t for t in times]
+    result["info"] = info
+    result["checks"] = {k: {"value": _finite(c["value"]), "limit": c["limit"]}
+                        for k, c in checks.items()}
+    return result
+
+
+def _finite(v):
+    return v if v is not None and math.isfinite(v) else None
+
+
+def report(result: dict, out=sys.stdout, err=sys.stderr) -> None:
+    info = result["info"]
+    print(f"# steps {info['steps']}, answers {info['answers']}, compiles "
+          f"inside the window {info['window_compiles']}, value dtype "
+          f"{info['value_dtype']}", file=err)
+    if "newton_step_p95_ms" in result["metrics"]:
+        print(f"# samples for newton_step_p95_ms: {len(info['step_ms'])}",
+              file=err)
+    if "busy_s_by_device" in info:
+        for d, b in info["busy_s_by_device"].items():
+            w = result["device"]["window_s"]
+            print(f"# {d} busy {b:.6f} s of {w:.6f} s, idle "
+                  f"{100 * (1 - b / w):.3f} %", file=err)
+    for name, m in result["metrics"].items():
+        print(f"# {name} {m['value']} {m['unit']}", file=err)
+    for name, c in result["checks"].items():
+        print(f"check {name} {c['value']} limit {c['limit']}", file=err)
+    err.flush()
+    print(json.dumps(result), file=out, flush=True)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--value-dtype", default=None,
+                    help="run the program's path in this value dtype "
+                         "(float32: the control)")
+    args = ap.parse_args(argv)
+    try:
+        result = run_cell(args.workload, args.seed, args.seconds,
+                          bool(args.trace), value_dtype=args.value_dtype)
+    except NoChip as e:
+        print(f"bench: {e}; nothing runs", file=sys.stderr)
+        return 2
+    report(result)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
