@@ -41,6 +41,7 @@ from ..distributed.scenario import make_scenario_sharding
 from ..kernels.backend import on_tpu
 from ..sparse.csc import CSC
 from ..sparse.layout import resolve_layout, unpack_planes
+from ..spans import span, timed
 from .factorize import JaxFactorizer
 from .planner import (
     MC64Scaling,
@@ -51,6 +52,9 @@ from .planner import (
 from .triangular import JaxTriangularSolver
 
 __all__ = ["GLU", "resolve_value_dtype"]
+
+# the stages of GLU construction that ``GLU.setup_seconds`` times
+SETUP_STAGES = ("scaling", "plan", "executor", "verify")
 
 
 def resolve_value_dtype(dtype) -> np.dtype:
@@ -178,19 +182,31 @@ class GLU:
         schedules and audit the fused runners' jaxprs.  Violations raise
         :class:`~repro.analysis.PlanVerificationError`; the report summary
         lands in ``solve_info["verify_report"]``.
+
+        ``setup_seconds`` records construction's host seconds by stage:
+        ``"scaling"`` (MC64 and the plan key), ``"plan"`` (the symbolic
+        plan build, 0 on a plan-cache hit), ``"executor"`` (factorizer and
+        triangular-solver build, plan arrays moved to the device) and
+        ``"verify"`` (0 with ``verify="off"``).  Each stage is also a
+        profiler span (``glu.scaling``, ``glu.plan``,
+        ``glu.executor_build``, ``glu.verify``) inside ``glu.setup``.
         """
-        plan, scaling, from_cache = plan_factorization(
-            A, ordering=ordering, symbolic=symbolic, mc64=mc64,
-            panel_threshold=panel_threshold, cache=plan_cache)
-        self._setup(
-            plan, scaling, A, from_cache=from_cache, dtype=dtype,
-            fuse_levels=fuse_levels, fuse_buckets=fuse_buckets,
-            bucket_waste=bucket_waste, jit_schedule=jit_schedule,
-            executable_cache=executable_cache, use_pallas=use_pallas,
-            static_pivot=static_pivot, refine=refine, refine_tol=refine_tol,
-            dense_tail=dense_tail, dense_tail_density=dense_tail_density,
-            mode_override=mode_override, interpret=interpret, layout=layout,
-            mesh=mesh, verify=verify)
+        self.setup_seconds = dict.fromkeys(SETUP_STAGES, 0.0)
+        with span("glu.setup"):
+            plan, scaling, from_cache = plan_factorization(
+                A, ordering=ordering, symbolic=symbolic, mc64=mc64,
+                panel_threshold=panel_threshold, cache=plan_cache,
+                seconds=self.setup_seconds)
+            self._setup(
+                plan, scaling, A, from_cache=from_cache, dtype=dtype,
+                fuse_levels=fuse_levels, fuse_buckets=fuse_buckets,
+                bucket_waste=bucket_waste, jit_schedule=jit_schedule,
+                executable_cache=executable_cache, use_pallas=use_pallas,
+                static_pivot=static_pivot, refine=refine,
+                refine_tol=refine_tol, dense_tail=dense_tail,
+                dense_tail_density=dense_tail_density,
+                mode_override=mode_override, interpret=interpret,
+                layout=layout, mesh=mesh, verify=verify)
 
     @classmethod
     def from_plan(
@@ -227,21 +243,25 @@ class GLU:
         """
         if not plan.matches_pattern(A):
             raise ValueError("matrix pattern differs from the plan's pattern")
-        scaling = compute_scaling(A, mc64)
-        if not np.array_equal(scaling.row_perm, plan.row_perm):
-            raise ValueError(
-                "MC64 matching of these values differs from the plan's "
-                "row permutation; rebuild the plan (e.g. GLU(A, ...))")
         self = cls.__new__(cls)
-        self._setup(
-            plan, scaling, A, from_cache=True, dtype=dtype,
-            fuse_levels=fuse_levels, fuse_buckets=fuse_buckets,
-            bucket_waste=bucket_waste, jit_schedule=jit_schedule,
-            executable_cache=executable_cache, use_pallas=use_pallas,
-            static_pivot=static_pivot, refine=refine, refine_tol=refine_tol,
-            dense_tail=dense_tail, dense_tail_density=dense_tail_density,
-            mode_override=mode_override, interpret=interpret, layout=layout,
-            mesh=mesh, verify=verify)
+        self.setup_seconds = dict.fromkeys(SETUP_STAGES, 0.0)
+        with span("glu.setup"):
+            with timed("glu.scaling", self.setup_seconds, "scaling"):
+                scaling = compute_scaling(A, mc64)
+            if not np.array_equal(scaling.row_perm, plan.row_perm):
+                raise ValueError(
+                    "MC64 matching of these values differs from the plan's "
+                    "row permutation; rebuild the plan (e.g. GLU(A, ...))")
+            self._setup(
+                plan, scaling, A, from_cache=True, dtype=dtype,
+                fuse_levels=fuse_levels, fuse_buckets=fuse_buckets,
+                bucket_waste=bucket_waste, jit_schedule=jit_schedule,
+                executable_cache=executable_cache, use_pallas=use_pallas,
+                static_pivot=static_pivot, refine=refine,
+                refine_tol=refine_tol, dense_tail=dense_tail,
+                dense_tail_density=dense_tail_density,
+                mode_override=mode_override, interpret=interpret,
+                layout=layout, mesh=mesh, verify=verify)
         return self
 
     def _setup(
@@ -300,9 +320,6 @@ class GLU:
         scaled = np.asarray(A.data) * self._scale_data
         self._A_perm = CSC(A.n, plan.perm_indptr, plan.perm_indices,
                            scaled[self._data_perm])
-        # scaled-A SpMV layout (permuted pattern) for iterative refinement
-        self._spmv_rows = jnp.asarray(plan.spmv_rows)
-        self._spmv_cols = jnp.asarray(plan.spmv_cols)
         self.pattern = plan.pattern
         self.levelization = plan.levelization
         self.plan = plan.fplan
@@ -310,20 +327,25 @@ class GLU:
         # given; sharding only ever applies to the batched entry points
         self.mesh = mesh
         self._shard = make_scenario_sharding(mesh)
-        self._factorizer = JaxFactorizer(
-            self.plan, dtype=dtype, fuse_levels=fuse_levels,
-            fuse_buckets=fuse_buckets, bucket_waste=bucket_waste,
-            jit_schedule=jit_schedule, executable_cache=executable_cache,
-            use_pallas=use_pallas, mode_override=mode_override,
-            interpret=interpret, dense_tail=dense_tail,
-            dense_tail_density=dense_tail_density, static_pivot=static_pivot,
-            layout=self.layout.name, shard=self._shard,
-        )
-        self._solver = JaxTriangularSolver(
-            self.plan, fuse=fuse_levels, fuse_buckets=fuse_buckets,
-            bucket_waste=bucket_waste, jit_schedule=jit_schedule,
-            executable_cache=executable_cache, layout=self.layout.name,
-            shard=self._shard)
+        with timed("glu.executor_build", self.setup_seconds, "executor"):
+            # scaled-A SpMV layout (permuted pattern) for iterative refinement
+            self._spmv_rows = jnp.asarray(plan.spmv_rows)
+            self._spmv_cols = jnp.asarray(plan.spmv_cols)
+            self._factorizer = JaxFactorizer(
+                self.plan, dtype=dtype, fuse_levels=fuse_levels,
+                fuse_buckets=fuse_buckets, bucket_waste=bucket_waste,
+                jit_schedule=jit_schedule, executable_cache=executable_cache,
+                use_pallas=use_pallas, mode_override=mode_override,
+                interpret=interpret, dense_tail=dense_tail,
+                dense_tail_density=dense_tail_density,
+                static_pivot=static_pivot, layout=self.layout.name,
+                shard=self._shard,
+            )
+            self._solver = JaxTriangularSolver(
+                self.plan, fuse=fuse_levels, fuse_buckets=fuse_buckets,
+                bucket_waste=bucket_waste, jit_schedule=jit_schedule,
+                executable_cache=executable_cache, layout=self.layout.name,
+                shard=self._shard)
         self._vals: Optional[jnp.ndarray] = None
         self._vals_batch: Optional[jnp.ndarray] = None
         self._a_vals: Optional[jnp.ndarray] = None
@@ -350,7 +372,8 @@ class GLU:
             # lazy import: analysis depends on core, not the other way round
             from ..analysis import verify_glu
 
-            self.verify_report = verify_glu(self, verify)
+            with timed("glu.verify", self.setup_seconds, "verify"):
+                self.verify_report = verify_glu(self, verify)
             self.verify_report.raise_if_violated()
 
     # -- numeric phase (repeatable) -----------------------------------------
@@ -359,21 +382,25 @@ class GLU:
         order (same pattern — the SPICE refactorization contract).  The
         batched factor cache is invalidated: the two caches can never refer
         to different matrix values."""
-        if a_data is None:
-            data = np.asarray(self._A_perm.data)
-        elif self._scale_identity:
-            data = np.asarray(a_data)[self._data_perm]
-        else:
-            data = (np.asarray(a_data) * self._scale_data)[self._data_perm]
-        self._a_vals = jnp.asarray(data, dtype=self.dtype)
-        self._a_abs = None                     # lazily built on refined solve
-        self._vals = self._factorizer.factorize(self._a_vals)
-        self._vals_batch = None
-        self._a_vals_batch = None
-        self._a_abs_batch = None
-        self._batch_size = self._batch_total = None
-        self._batch_pad = 0
-        self._set_fact_info(self._vals, self._a_vals, batched=False)
+        with span("glu.factorize"):
+            with span("glu.prep"):
+                if a_data is None:
+                    data = np.asarray(self._A_perm.data)
+                elif self._scale_identity:
+                    data = np.asarray(a_data)[self._data_perm]
+                else:
+                    data = ((np.asarray(a_data) * self._scale_data)
+                            [self._data_perm])
+            with span("glu.h2d"):
+                self._a_vals = jnp.asarray(data, dtype=self.dtype)
+            self._a_abs = None                 # lazily built on refined solve
+            self._vals = self._factorizer.factorize(self._a_vals)
+            self._vals_batch = None
+            self._a_vals_batch = None
+            self._a_abs_batch = None
+            self._batch_size = self._batch_total = None
+            self._batch_pad = 0
+            self._set_fact_info(self._vals, self._a_vals, batched=False)
         return self
 
     def factorized_values(self) -> jnp.ndarray:
@@ -415,30 +442,40 @@ class GLU:
         numbering) of b's nonzero support — prunes the triangular-solve
         schedule to the reach closure of the pattern (raises if b is
         nonzero outside it)."""
-        if self._vals is None:
-            if self._vals_batch is not None:
-                raise RuntimeError(
-                    "the active factorization is batched — use solve_batched(),"
-                    " or call factorize() to refactorize single-matrix first")
-            self.factorize()
-        k = self.refine_default if refine is None else int(refine)
-        pat = self._map_rhs_pattern(rhs_pattern, b)
-        bp = (np.asarray(b) * self.Dr)[self._inv_row]
-        if k > 0:
-            if self._a_abs is None:
+        with span("glu.solve"):
+            if self._vals is None:
+                if self._vals_batch is not None:
+                    raise RuntimeError(
+                        "the active factorization is batched — use "
+                        "solve_batched(), or call factorize() to refactorize "
+                        "single-matrix first")
+                self.factorize()
+            k = self.refine_default if refine is None else int(refine)
+            with span("glu.prep"):
+                pat = self._map_rhs_pattern(rhs_pattern, b)
+                bp = (np.asarray(b) * self.Dr)[self._inv_row]
+            launched = 0          # device programs besides the solver's
+            if k > 0 and self._a_abs is None:
                 self._a_abs = jnp.abs(self._a_vals)
-            xp, rinfo = self._solver.solve_refined(
-                self._vals, bp, self._spmv_rows, self._spmv_cols,
-                self._a_vals, self._a_abs, max_iter=k, tol=self.refine_tol,
-                rhs_pattern=pat)
-            xp = np.asarray(xp)
-        else:
-            xp = np.asarray(self._solver.solve(self._vals, bp,
-                                               rhs_pattern=pat))
-            rinfo = {"refine_iters": 0, "backward_error": None,
-                     "converged": None, "host_syncs": 0}
-        self._set_solve_info(rinfo)
-        return xp[self.col_map] * self.Dc
+                launched = 1
+            with span("glu.h2d"):
+                # refinement runs in the value dtype; a plain solve's
+                # program casts the rhs itself
+                bpd = jnp.asarray(bp, dtype=self.dtype if k > 0 else None)
+            if k > 0:
+                xp, rinfo = self._solver.solve_refined(
+                    self._vals, bpd, self._spmv_rows, self._spmv_cols,
+                    self._a_vals, self._a_abs, max_iter=k,
+                    tol=self.refine_tol, rhs_pattern=pat)
+            else:
+                xp = self._solver.solve(self._vals, bpd, rhs_pattern=pat)
+                rinfo = {"refine_iters": 0, "backward_error": None,
+                         "converged": None, "host_syncs": 0}
+            with span("glu.d2h"):
+                xp = np.asarray(xp)
+            self._set_solve_info(rinfo, launched)
+            with span("glu.post"):
+                return xp[self.col_map] * self.Dc
 
     def solve_multi(self, b_multi, refine: Optional[int] = None,
                     rhs_pattern=None) -> np.ndarray:
@@ -447,34 +484,45 @@ class GLU:
         K seed vectors, one Jacobian).  ``b_multi`` is (K, n), returns
         (K, n); each level group is one device dispatch for all K rhs.
         ``rhs_pattern`` is the union support of all rows."""
-        if self._vals is None:
-            if self._vals_batch is not None:
-                raise RuntimeError(
-                    "the active factorization is batched — use solve_batched(),"
-                    " or call factorize() to refactorize single-matrix first")
-            self.factorize()
-        b = np.asarray(b_multi)
-        if b.ndim != 2 or b.shape[1] != self.n:
-            raise ValueError(f"expected (K, {self.n}) rhs, got {b.shape}")
-        k = self.refine_default if refine is None else int(refine)
-        pat = self._map_rhs_pattern(rhs_pattern, b)
-        bp = (b * self.Dr[None, :])[:, self._inv_row]
-        if k > 0:
-            if self._a_abs is None:
+        with span("glu.solve_multi"):
+            if self._vals is None:
+                if self._vals_batch is not None:
+                    raise RuntimeError(
+                        "the active factorization is batched — use "
+                        "solve_batched(), or call factorize() to refactorize "
+                        "single-matrix first")
+                self.factorize()
+            b = np.asarray(b_multi)
+            if b.ndim != 2 or b.shape[1] != self.n:
+                raise ValueError(f"expected (K, {self.n}) rhs, got {b.shape}")
+            k = self.refine_default if refine is None else int(refine)
+            with span("glu.prep"):
+                pat = self._map_rhs_pattern(rhs_pattern, b)
+                bp = (b * self.Dr[None, :])[:, self._inv_row]
+            launched = 0          # device programs besides the solver's
+            if k > 0 and self._a_abs is None:
                 self._a_abs = jnp.abs(self._a_vals)
-            xp, rinfo = self._solver.solve_refined_multi(
-                self._vals, bp, self._spmv_rows, self._spmv_cols,
-                self._a_vals, self._a_abs, max_iter=k, tol=self.refine_tol,
-                rhs_pattern=pat)
-            xp = np.asarray(xp)
-        else:
-            xp = np.asarray(self._solver.solve_multi(self._vals, bp,
-                                                     rhs_pattern=pat))
-            rinfo = {"refine_iters": np.zeros(b.shape[0], dtype=np.int64),
-                     "backward_error": None, "converged": None,
-                     "host_syncs": 0}
-        self._set_solve_info(rinfo)
-        return xp[:, self.col_map] * self.Dc[None, :]
+                launched = 1
+            with span("glu.h2d"):
+                # refinement runs in the value dtype; a plain solve's
+                # program casts the rhs itself
+                bpd = jnp.asarray(bp, dtype=self.dtype if k > 0 else None)
+            if k > 0:
+                xp, rinfo = self._solver.solve_refined_multi(
+                    self._vals, bpd, self._spmv_rows, self._spmv_cols,
+                    self._a_vals, self._a_abs, max_iter=k,
+                    tol=self.refine_tol, rhs_pattern=pat)
+            else:
+                xp = self._solver.solve_multi(self._vals, bpd,
+                                              rhs_pattern=pat)
+                rinfo = {"refine_iters": np.zeros(b.shape[0], dtype=np.int64),
+                         "backward_error": None, "converged": None,
+                         "host_syncs": 0}
+            with span("glu.d2h"):
+                xp = np.asarray(xp)
+            self._set_solve_info(rinfo, launched)
+            with span("glu.post"):
+                return xp[:, self.col_map] * self.Dc[None, :]
 
     # -- batched numeric phase (one plan, many matrices) ----------------------
     def factorize_batched(self, a_data_batch) -> "GLU":
@@ -484,41 +532,52 @@ class GLU:
         original CSC entry order (the Monte-Carlo / parameter-sweep
         refactorization contract: one symbolic plan, many value vectors).
         The single-matrix factor cache is invalidated."""
-        data = np.asarray(a_data_batch)
-        if data.ndim != 2:
-            raise ValueError(f"expected (B, nnz) values, got shape {data.shape}")
-        if self._scale_identity:
-            scaled = data[:, self._data_perm]
-        else:
-            scaled = (data * self._scale_data[None, :])[:, self._data_perm]
-        B = scaled.shape[0]
-        self._batch_size = self._batch_total = B
-        self._batch_pad = 0
-        if self._shard is not None and B > 1:
-            # non-divisible batches are padded with copies of the LAST
-            # scenario (a known-factorizable system, so the pad rows can
-            # never poison diagnostics with inf/NaN) and masked out of
-            # results and convergence below — the scenario-axis analogue of
-            # the silent-replicate rule in distributed/sharding.py.  B == 1
-            # stays unsharded: padding a single matrix across the mesh buys
-            # nothing.
-            total = self._shard.pad(B)
-            if total != B:
-                scaled = np.concatenate(
-                    [scaled, np.repeat(scaled[-1:], total - B, axis=0)])
-            self._batch_total = total
-            self._batch_pad = total - B
-        self._a_vals_batch = jnp.asarray(scaled, dtype=self.dtype)
-        if self._shard is not None and self._batch_total % self._shard.n_shards == 0:
-            # place the batch sharded BEFORE dispatch so the runner never
-            # reshuffles it (donation-safe: the runner does not donate it)
-            self._a_vals_batch = self._shard.shard_batch(self._a_vals_batch)
-        self._a_abs_batch = None               # lazily built on refined solve
-        self._vals_batch = self._factorizer.factorize_batched(self._a_vals_batch)
-        self._vals = None
-        self._a_vals = None
-        self._a_abs = None
-        self._set_fact_info(self._vals_batch, self._a_vals_batch, batched=True)
+        with span("glu.factorize_batched"):
+            with span("glu.prep"):
+                data = np.asarray(a_data_batch)
+                if data.ndim != 2:
+                    raise ValueError(
+                        f"expected (B, nnz) values, got shape {data.shape}")
+                if self._scale_identity:
+                    scaled = data[:, self._data_perm]
+                else:
+                    scaled = ((data * self._scale_data[None, :])
+                              [:, self._data_perm])
+                B = scaled.shape[0]
+                self._batch_size = self._batch_total = B
+                self._batch_pad = 0
+                if self._shard is not None and B > 1:
+                    # non-divisible batches are padded with copies of the
+                    # LAST scenario (a known-factorizable system, so the pad
+                    # rows can never poison diagnostics with inf/NaN) and
+                    # masked out of results and convergence below — the
+                    # scenario-axis analogue of the silent-replicate rule in
+                    # distributed/sharding.py.  B == 1 stays unsharded:
+                    # padding a single matrix across the mesh buys nothing.
+                    total = self._shard.pad(B)
+                    if total != B:
+                        scaled = np.concatenate(
+                            [scaled,
+                             np.repeat(scaled[-1:], total - B, axis=0)])
+                    self._batch_total = total
+                    self._batch_pad = total - B
+            with span("glu.h2d"):
+                self._a_vals_batch = jnp.asarray(scaled, dtype=self.dtype)
+                if (self._shard is not None
+                        and self._batch_total % self._shard.n_shards == 0):
+                    # place the batch sharded BEFORE dispatch so the runner
+                    # never reshuffles it (donation-safe: the runner does
+                    # not donate it)
+                    self._a_vals_batch = self._shard.shard_batch(
+                        self._a_vals_batch)
+            self._a_abs_batch = None           # lazily built on refined solve
+            self._vals_batch = self._factorizer.factorize_batched(
+                self._a_vals_batch)
+            self._vals = None
+            self._a_vals = None
+            self._a_abs = None
+            self._set_fact_info(self._vals_batch, self._a_vals_batch,
+                                batched=True)
         return self
 
     def factorized_values_batched(self) -> jnp.ndarray:
@@ -536,47 +595,55 @@ class GLU:
         """Solve A_i x_i = b_i for every matrix of the current batched
         factorization; ``b_batch`` is (B, n), returns (B, n).  A
         ``rhs_pattern`` is shared by the batch (union support)."""
-        if self._vals_batch is None:
-            raise RuntimeError("call factorize_batched() first")
-        B = np.asarray(b_batch).shape[0]
-        if self._batch_size is not None and B != self._batch_size:
-            raise ValueError(
-                f"rhs batch of {B} does not match the factorized batch of "
-                f"{self._batch_size}")
-        k = self.refine_default if refine is None else int(refine)
-        pat = self._map_rhs_pattern(rhs_pattern, np.asarray(b_batch))
-        bp = (np.asarray(b_batch) * self.Dr[None, :])[:, self._inv_row]
-        if self._batch_pad:
-            # zero rhs rows for the pad scenarios: their solution is exactly
-            # zero (and their backward error 0/0 counts as converged), so
-            # refinement never iterates for them
-            bp = np.concatenate(
-                [bp, np.zeros((self._batch_pad, bp.shape[1]), dtype=bp.dtype)])
-        bpd = jnp.asarray(bp)
-        if (self._shard is not None
-                and bpd.shape[0] % self._shard.n_shards == 0):
-            bpd = self._shard.shard_batch(bpd)
-        if k > 0:
-            if self._a_abs_batch is None:
+        with span("glu.solve_batched"):
+            if self._vals_batch is None:
+                raise RuntimeError("call factorize_batched() first")
+            B = np.asarray(b_batch).shape[0]
+            if self._batch_size is not None and B != self._batch_size:
+                raise ValueError(
+                    f"rhs batch of {B} does not match the factorized batch "
+                    f"of {self._batch_size}")
+            k = self.refine_default if refine is None else int(refine)
+            with span("glu.prep"):
+                pat = self._map_rhs_pattern(rhs_pattern, np.asarray(b_batch))
+                bp = (np.asarray(b_batch) * self.Dr[None, :])[:, self._inv_row]
+                if self._batch_pad:
+                    # zero rhs rows for the pad scenarios: their solution is
+                    # exactly zero (and their backward error 0/0 counts as
+                    # converged), so refinement never iterates for them
+                    bp = np.concatenate(
+                        [bp, np.zeros((self._batch_pad, bp.shape[1]),
+                                      dtype=bp.dtype)])
+            with span("glu.h2d"):
+                bpd = jnp.asarray(bp)
+                if (self._shard is not None
+                        and bpd.shape[0] % self._shard.n_shards == 0):
+                    bpd = self._shard.shard_batch(bpd)
+            launched = 0          # device programs besides the solver's
+            if k > 0 and self._a_abs_batch is None:
                 self._a_abs_batch = jnp.abs(self._a_vals_batch)
-            xp, rinfo = self._solver.solve_refined_batched(
-                self._vals_batch, bpd, self._spmv_rows, self._spmv_cols,
-                self._a_vals_batch, self._a_abs_batch,
-                max_iter=k, tol=self.refine_tol, rhs_pattern=pat)
-            xp = np.asarray(xp)
-            if self._batch_pad:
-                rinfo = {key: (v[:B] if isinstance(v, np.ndarray) else v)
-                         for key, v in rinfo.items()}
-        else:
-            xp = np.asarray(self._solver.solve_batched(self._vals_batch, bpd,
-                                                       rhs_pattern=pat))
-            rinfo = {"refine_iters": np.zeros(B, dtype=np.int64),
-                     "backward_error": None, "converged": None,
-                     "host_syncs": 0}
-        if self._batch_pad:
-            xp = xp[:B]
-        self._set_solve_info(rinfo)
-        return xp[:, self.col_map] * self.Dc[None, :]
+                launched = 1
+            if k > 0:
+                xp, rinfo = self._solver.solve_refined_batched(
+                    self._vals_batch, bpd, self._spmv_rows, self._spmv_cols,
+                    self._a_vals_batch, self._a_abs_batch,
+                    max_iter=k, tol=self.refine_tol, rhs_pattern=pat)
+            else:
+                xp = self._solver.solve_batched(self._vals_batch, bpd,
+                                                rhs_pattern=pat)
+                rinfo = {"refine_iters": np.zeros(B, dtype=np.int64),
+                         "backward_error": None, "converged": None,
+                         "host_syncs": 0}
+            with span("glu.d2h"):
+                xp = np.asarray(xp)
+            with span("glu.post"):
+                if self._batch_pad:
+                    xp = xp[:B]
+                    rinfo = {key: (v[:B] if isinstance(v, np.ndarray) else v)
+                             for key, v in rinfo.items()}
+                x = xp[:, self.col_map] * self.Dc[None, :]
+            self._set_solve_info(rinfo, launched)
+            return x
 
     def refactorize_solve(self, a_data_batch, b_batch,
                           refine: Optional[int] = None,
@@ -585,14 +652,16 @@ class GLU:
         step of a parameter sweep).  Accepts (B, nnz)+(B, n) or a single
         (nnz,)+(n,) pair; the factored values stay on device between the
         two phases and are kept for later ``solve_batched`` calls."""
-        data = np.asarray(a_data_batch)
-        b = np.asarray(b_batch)
-        single = data.ndim == 1
-        if single:
-            data, b = data[None], b[None]
-        self.factorize_batched(data)
-        x = self.solve_batched(b, refine=refine, rhs_pattern=rhs_pattern)
-        if single:
+        with span("glu.refactorize_solve"):
+            data = np.asarray(a_data_batch)
+            b = np.asarray(b_batch)
+            single = data.ndim == 1
+            if single:
+                data, b = data[None], b[None]
+            self.factorize_batched(data)
+            x = self.solve_batched(b, refine=refine, rhs_pattern=rhs_pattern)
+            if not single:
+                return x
             self._vals = self._vals_batch[0]
             self._a_vals = self._a_vals_batch[0]
             self._a_abs = (None if self._a_abs_batch is None
@@ -613,7 +682,6 @@ class GLU:
                     if v is not None and not isinstance(v, (bool, int, float)):
                         self._info[key] = np.asarray(v)[0]
             return x[0]
-        return x
 
     # -- diagnostics ----------------------------------------------------------
     def _set_fact_info(self, factored_vals, a_vals, batched: bool) -> None:
@@ -661,7 +729,9 @@ class GLU:
                               else self.verify_report.summary()),
         }
 
-    def _set_solve_info(self, rinfo: dict) -> None:
+    def _set_solve_info(self, rinfo: dict, launched: int) -> None:
+        """Record the latest solve; ``launched`` counts the device programs
+        the facade launched around the solver's own (the lazy |A|)."""
         if self._info is None:
             self._info = {"batched": False, "pivot_growth": None,
                           "min_diag": None, "n_perturbed": None,
@@ -676,7 +746,8 @@ class GLU:
                               None if self.verify_report is None
                               else self.verify_report.summary())}
         self._info.update(rinfo)
-        self._info["solve_dispatches"] = self._solver.last_n_dispatches
+        self._info["solve_dispatches"] = (self._solver.last_n_dispatches
+                                          + launched)
 
     @property
     def refine_converged(self):

@@ -26,6 +26,7 @@ from jax.sharding import PartitionSpec as P
 from ..distributed.collectives import psum_exact
 from ..kernels.backend import check_pallas_dtype, resolve_interpret
 from ..sparse.layout import pabs, pack_planes, pdiv, pmul, resolve_layout
+from ..spans import named
 from .executor import resolve_executable_cache
 from .plan import (
     MODE_FLAT,
@@ -655,7 +656,7 @@ def _build_factorize_runner(kinds, *, entry, batched, robust, interpret,
 
     donate = (0,) if entry == "filled" else ()
     if shard is None:
-        return jax.jit(run, donate_argnums=donate)
+        return jax.jit(named("glu_factorize", run), donate_argnums=donate)
     if not batched:
         raise ValueError("scenario sharding requires a batched entry")
     bspec = shard.spec
@@ -671,7 +672,7 @@ def _build_factorize_runner(kinds, *, entry, batched, robust, interpret,
         out_specs = bspec
     mapped = jax.shard_map(run, mesh=shard.mesh, in_specs=in_specs,
                            out_specs=out_specs, check_vma=False)
-    return jax.jit(mapped, donate_argnums=donate)
+    return jax.jit(named("glu_factorize", mapped), donate_argnums=donate)
 
 
 class JaxFactorizer:

@@ -29,13 +29,13 @@ import dataclasses
 import os
 import pickle
 import threading
-import time
 from collections import OrderedDict
 from typing import Optional, Union
 
 import numpy as np
 
 from ..sparse.csc import CSC, pattern_digest
+from ..spans import timed
 from .dependency import Levelization, levelize_relaxed
 from .ordering import (
     fill_reducing_ordering,
@@ -196,53 +196,55 @@ def build_symbolic_plan(
     panel_threshold: int = 16,
     key: Optional[str] = None,
 ) -> SymbolicPlan:
-    """Run the pattern-dependent preprocessing pipeline once."""
-    t_total = time.perf_counter()
-    ordering = resolve_ordering_method(n, ordering)
-    symbolic = resolve_symbolic_method(n, symbolic)
-    indptr = np.asarray(indptr, dtype=np.int64)
-    indices = np.asarray(indices, dtype=np.int64)
-    row_perm = np.asarray(row_perm, dtype=np.int64)
-    if key is None:
-        key = plan_key(n, indptr, indices, row_perm, ordering, symbolic,
-                       panel_threshold)
-    rows0 = indices
-    cols0 = np.repeat(np.arange(n, dtype=np.int64), np.diff(indptr))
+    """Run the pattern-dependent preprocessing pipeline once.
 
-    t0 = time.perf_counter()
-    # fill-reducing ordering runs on the row-permuted pattern (values are
-    # irrelevant to mindeg/rcm, so a pattern-only CSC suffices)
-    A_rp = CSC(n, indptr.astype(np.int32), indices.astype(np.int32),
-               np.ones(len(rows0))).permute(row_perm,
-                                            np.arange(n, dtype=np.int64))
-    sym_perm = fill_reducing_ordering(A_rp, ordering)
-    row_map = sym_perm[row_perm]
-    col_map = sym_perm
-    inv_row = np.argsort(row_map)
-    t_ordering = time.perf_counter() - t0
+    ``build_seconds`` holds each stage's host seconds and their ``total``;
+    the build is the span ``glu.plan`` and each stage ``glu.plan.<stage>``.
+    """
+    secs: dict = {}
+    with timed("glu.plan", secs, "total"):
+        ordering = resolve_ordering_method(n, ordering)
+        symbolic = resolve_symbolic_method(n, symbolic)
+        indptr = np.asarray(indptr, dtype=np.int64)
+        indices = np.asarray(indices, dtype=np.int64)
+        row_perm = np.asarray(row_perm, dtype=np.int64)
+        if key is None:
+            key = plan_key(n, indptr, indices, row_perm, ordering, symbolic,
+                           panel_threshold)
+        rows0 = indices
+        cols0 = np.repeat(np.arange(n, dtype=np.int64), np.diff(indptr))
 
-    t0 = time.perf_counter()
-    # permuted pattern + original-entry-order -> permuted-entry-order map
-    data_perm = np.lexsort((row_map[rows0], col_map[cols0]))
-    perm_rows = row_map[rows0][data_perm]
-    perm_cols = col_map[cols0][data_perm]
-    perm_indptr = np.concatenate(
-        [[0], np.cumsum(np.bincount(perm_cols, minlength=n))]).astype(np.int32)
-    perm_indices = perm_rows.astype(np.int32)
-    A_perm = CSC(n, perm_indptr, perm_indices, np.ones(len(perm_rows)))
-    t_permute = time.perf_counter() - t0
+        with timed("glu.plan.ordering", secs, "ordering"):
+            # fill-reducing ordering runs on the row-permuted pattern (values
+            # are irrelevant to mindeg/rcm, so a pattern-only CSC suffices)
+            A_rp = CSC(n, indptr.astype(np.int32), indices.astype(np.int32),
+                       np.ones(len(rows0))).permute(
+                           row_perm, np.arange(n, dtype=np.int64))
+            sym_perm = fill_reducing_ordering(A_rp, ordering)
+            row_map = sym_perm[row_perm]
+            col_map = sym_perm
+            inv_row = np.argsort(row_map)
 
-    t0 = time.perf_counter()
-    pattern = symbolic_fillin(A_perm, symbolic)
-    t_symbolic = time.perf_counter() - t0
+        with timed("glu.plan.permute", secs, "permute"):
+            # permuted pattern + original-entry-order -> permuted-entry-order
+            data_perm = np.lexsort((row_map[rows0], col_map[cols0]))
+            perm_rows = row_map[rows0][data_perm]
+            perm_cols = col_map[cols0][data_perm]
+            perm_indptr = np.concatenate(
+                [[0], np.cumsum(np.bincount(perm_cols, minlength=n))]
+            ).astype(np.int32)
+            perm_indices = perm_rows.astype(np.int32)
+            A_perm = CSC(n, perm_indptr, perm_indices, np.ones(len(perm_rows)))
 
-    t0 = time.perf_counter()
-    levelization = levelize_relaxed(pattern)
-    t_levelize = time.perf_counter() - t0
+        with timed("glu.plan.symbolic", secs, "symbolic"):
+            pattern = symbolic_fillin(A_perm, symbolic)
 
-    t0 = time.perf_counter()
-    fplan = build_plan(pattern, levelization, panel_threshold=panel_threshold)
-    t_plan = time.perf_counter() - t0
+        with timed("glu.plan.levelize", secs, "levelize"):
+            levelization = levelize_relaxed(pattern)
+
+        with timed("glu.plan.plan", secs, "plan"):
+            fplan = build_plan(pattern, levelization,
+                               panel_threshold=panel_threshold)
 
     return SymbolicPlan(
         n=n,
@@ -264,14 +266,7 @@ def build_symbolic_plan(
         fplan=fplan,
         spmv_rows=perm_rows.astype(np.int32),
         spmv_cols=perm_cols.astype(np.int32),
-        build_seconds={
-            "ordering": t_ordering,
-            "permute": t_permute,
-            "symbolic": t_symbolic,
-            "levelize": t_levelize,
-            "plan": t_plan,
-            "total": time.perf_counter() - t_total,
-        },
+        build_seconds=secs,
     )
 
 
@@ -400,16 +395,24 @@ def plan_factorization(
     mc64: Union[str, bool, None] = "scale",
     panel_threshold: int = 16,
     cache: Union[PlanCache, str, None] = "default",
+    seconds: Optional[dict] = None,
 ):
     """Full preprocessing with plan reuse.
 
     Runs the value-dependent MC64 stage, then either fetches the matching
     pattern-level :class:`SymbolicPlan` from ``cache`` or builds and stores
     it.  Returns ``(plan, scaling, from_cache)``.
+
+    ``seconds``, when given, receives the host seconds of ``"scaling"`` (MC64
+    and the plan key: the span ``glu.scaling``) and of ``"plan"`` (the
+    build's ``build_seconds["total"]``; 0 on a cache hit).
     """
-    scaling = compute_scaling(A, mc64)
-    key = plan_key(A.n, A.indptr, A.indices, scaling.row_perm,
-                   ordering, symbolic, panel_threshold)
+    seconds = {} if seconds is None else seconds
+    with timed("glu.scaling", seconds, "scaling"):
+        scaling = compute_scaling(A, mc64)
+        key = plan_key(A.n, A.indptr, A.indices, scaling.row_perm,
+                       ordering, symbolic, panel_threshold)
+    seconds["plan"] = 0.0
     c = _resolve_cache(cache)
     plan = c.get(key) if c is not None else None
     if plan is not None:
@@ -417,6 +420,7 @@ def plan_factorization(
     plan = build_symbolic_plan(A.n, A.indptr, A.indices, scaling.row_perm,
                                ordering=ordering, symbolic=symbolic,
                                panel_threshold=panel_threshold, key=key)
+    seconds["plan"] = plan.build_seconds["total"]
     if c is not None:
         c.stats.builds += 1
         c.put(key, plan)

@@ -39,6 +39,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..kernels.ops import masked_correction, spmv
 from ..sparse.layout import pack_planes, pdiv, pmul, unpack_planes
+from ..spans import named, span
 from .executor import resolve_executable_cache
 from .plan import FactorizePlan, bucketize, choose_buckets, pow2_pad
 
@@ -111,12 +112,15 @@ def _residual_berr_body(rows, cols, a_vals, a_abs, x, b, n):
     return r, berr
 
 
+# every variant runs as the program ``glu_residual``
 @partial(jax.jit, static_argnames=("n",))
+@partial(named, "glu_residual")
 def _residual_berr(rows, cols, a_vals, a_abs, x, b, *, n):
     return _residual_berr_body(rows, cols, a_vals, a_abs, x, b, n)
 
 
 @partial(jax.jit, static_argnames=("n",))
+@partial(named, "glu_residual")
 def _residual_berr_batched(rows, cols, a_vals, a_abs, x, b, *, n):
     return jax.vmap(
         lambda av, aa, xx, bb: _residual_berr_body(rows, cols, av, aa, xx, bb, n)
@@ -125,6 +129,7 @@ def _residual_berr_batched(rows, cols, a_vals, a_abs, x, b, *, n):
 
 # Many-RHS twin: one value vector, (K, n) right-hand sides.
 @partial(jax.jit, static_argnames=("n",))
+@partial(named, "glu_residual")
 def _residual_berr_multi(rows, cols, a_vals, a_abs, x, b, *, n):
     return jax.vmap(
         lambda xx, bb: _residual_berr_body(rows, cols, a_vals, a_abs, xx, bb, n)
@@ -240,7 +245,7 @@ def _build_trisolve_runner(kind: str, planar: bool = False, shard=None):
         fn = jax.shard_map(fn, mesh=shard.mesh,
                            in_specs=(bspec, bspec, P(), P()),
                            out_specs=bspec, check_vma=False)
-    return jax.jit(fn)
+    return jax.jit(named("glu_trisolve", fn))
 
 
 class JaxTriangularSolver:
@@ -571,57 +576,66 @@ class JaxTriangularSolver:
         masked on DEVICE (``masked_correction``) and the backward error only
         crosses to the host once per ``sync_every`` sweeps — the common
         ``max_iter <= sync_every`` case pays exactly one transfer."""
-        n = self.plan.n
-        # planar factors still refine against the NATIVE complex system:
-        # casting b to vals.dtype would truncate a complex rhs to the real
-        # plane dtype, so the cast targets the interface dtype instead
-        b = jnp.asarray(b, dtype=self._iface_dtype(vals))
-        if kind == "single":
-            solve = self.solve
-            res_fn = _residual_berr
-        elif kind == "batched":
-            solve = self.solve_batched
-            res_fn = _residual_berr_batched
-        else:
-            solve = self.solve_multi
-            res_fn = _residual_berr_multi
-        x = solve(vals, b, rhs_pattern=rhs_pattern)
-        n_disp = self.last_n_dispatches + 1    # + the residual/berr pass
-        r, berr = res_fn(a_rows, a_cols, a_vals, a_abs, x, b, n=n)
-        iters = jnp.zeros(berr.shape, dtype=jnp.int32)
-        syncs = 0
-        done = 0
-        berr_h = iters_h = None
-        while done < max_iter:
-            chunk = min(max(1, int(sync_every)), max_iter - done)
-            for _ in range(chunk):
-                d = solve(vals, r)
-                n_disp += self.last_n_dispatches + 2   # mask + residual
-                x = masked_correction(x, d, berr, tol)
-                iters = iters + (berr > tol)
-                r, berr = res_fn(a_rows, a_cols, a_vals, a_abs, x, b, n=n)
-            done += chunk
-            berr_h, iters_h = jax.device_get((berr, iters))
-            syncs += 1
-            if np.all(berr_h <= tol):
-                break
-        if berr_h is None:                      # max_iter == 0
-            berr_h, iters_h = jax.device_get((berr, iters))
-            syncs += 1
-        self.last_n_dispatches = n_disp
-        if kind == "single":
-            berr_out = float(berr_h)
-            info = {"refine_iters": int(iters_h),
-                    "backward_error": berr_out,
-                    "converged": berr_out <= tol,
-                    "host_syncs": syncs}
-        else:
-            berr_out = np.asarray(berr_h)
-            info = {"refine_iters": np.asarray(iters_h, dtype=np.int64),
-                    "backward_error": berr_out,
-                    "converged": berr_out <= tol,
-                    "host_syncs": syncs}
-        return x, info
+        with span("glu.refine"):
+            n = self.plan.n
+            # planar factors still refine against the NATIVE complex system:
+            # casting b to vals.dtype would truncate a complex rhs to the real
+            # plane dtype, so the cast targets the interface dtype instead
+            b = jnp.asarray(b, dtype=self._iface_dtype(vals))
+            if kind == "single":
+                solve = self.solve
+                res_fn = _residual_berr
+            elif kind == "batched":
+                solve = self.solve_batched
+                res_fn = _residual_berr_batched
+            else:
+                solve = self.solve_multi
+                res_fn = _residual_berr_multi
+            # ``n_disp`` counts every device program this call launches
+            x = solve(vals, b, rhs_pattern=rhs_pattern)
+            n_disp = self.last_n_dispatches + 1    # + the residual/berr pass
+            r, berr = res_fn(a_rows, a_cols, a_vals, a_abs, x, b, n=n)
+            iters = jnp.zeros(berr.shape, dtype=jnp.int32)
+            # jnp.zeros launches convert_element_type, plus broadcast_in_dim
+            # for a vector
+            n_disp += 1 if berr.ndim == 0 else 2
+            syncs = 0
+            done = 0
+            berr_h = iters_h = None
+            while done < max_iter:
+                chunk = min(max(1, int(sync_every)), max_iter - done)
+                for _ in range(chunk):
+                    d = solve(vals, r)
+                    x = masked_correction(x, d, berr, tol)
+                    iters = iters + (berr > tol)
+                    r, berr = res_fn(a_rows, a_cols, a_vals, a_abs, x, b,
+                                     n=n)
+                    # the solve's, then correction, compare, count, residual
+                    n_disp += self.last_n_dispatches + 4
+                done += chunk
+                with span("glu.sync"):
+                    berr_h, iters_h = jax.device_get((berr, iters))
+                syncs += 1
+                if np.all(berr_h <= tol):
+                    break
+            if berr_h is None:                      # max_iter == 0
+                with span("glu.sync"):
+                    berr_h, iters_h = jax.device_get((berr, iters))
+                syncs += 1
+            self.last_n_dispatches = n_disp
+            if kind == "single":
+                berr_out = float(berr_h)
+                info = {"refine_iters": int(iters_h),
+                        "backward_error": berr_out,
+                        "converged": berr_out <= tol,
+                        "host_syncs": syncs}
+            else:
+                berr_out = np.asarray(berr_h)
+                info = {"refine_iters": np.asarray(iters_h, dtype=np.int64),
+                        "backward_error": berr_out,
+                        "converged": berr_out <= tol,
+                        "host_syncs": syncs}
+            return x, info
 
     def solve_refined(self, vals, b, a_rows, a_cols, a_vals, a_abs,
                       max_iter: int, tol: float, rhs_pattern=None,

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from ..sparse.layout import pabs, pdiv, pmul
+from ..spans import named
 from .dense_lu import dense_lu
 from .level_update import segmented_accumulate
 
@@ -275,9 +276,10 @@ def _factor_stats_body(vals, diag_idx, a_max):
     return growth, jnp.min(d)
 
 
-factor_stats = jax.jit(_factor_stats_body)
-factor_stats_batched = jax.jit(jax.vmap(_factor_stats_body,
-                                        in_axes=(0, None, 0)))
+# every factor_stats* variant runs as the program ``glu_factor_stats``
+factor_stats = jax.jit(named("glu_factor_stats", _factor_stats_body))
+factor_stats_batched = jax.jit(named(
+    "glu_factor_stats", jax.vmap(_factor_stats_body, in_axes=(0, None, 0))))
 
 
 def _factor_stats_planar_body(vals, diag_idx, a_max):
@@ -288,12 +290,15 @@ def _factor_stats_planar_body(vals, diag_idx, a_max):
     return growth, jnp.min(d)
 
 
-factor_stats_planar = jax.jit(_factor_stats_planar_body)
-factor_stats_planar_batched = jax.jit(jax.vmap(_factor_stats_planar_body,
-                                               in_axes=(0, None, 0)))
+factor_stats_planar = jax.jit(named("glu_factor_stats",
+                                   _factor_stats_planar_body))
+factor_stats_planar_batched = jax.jit(named(
+    "glu_factor_stats",
+    jax.vmap(_factor_stats_planar_body, in_axes=(0, None, 0))))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
+@functools.partial(named, "glu_correct")
 def masked_correction(x, d, berr, tol):
     """``x + d`` where the solve is still above tolerance, ``x`` unchanged
     where it has converged — the device-side convergence mask that lets

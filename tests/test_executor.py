@@ -349,6 +349,37 @@ def test_glu_solve_info_dispatch_counters(problem):
     assert li["solve_dispatches"] >= 10 * info["solve_dispatches"]
 
 
+# Programs a refined solve launches on the fused path: the first trisolve
+# and residual, the iteration count's zeros (convert_element_type, plus
+# broadcast_in_dim for a vector), then per sweep trisolve, correction,
+# compare, count add and residual; |A| once per factorization.
+@pytest.mark.parametrize("kind, zeros", [("single", 1), ("batched", 2),
+                                         ("multi", 2)])
+def test_glu_refined_solve_dispatch_count(problem, kind, zeros):
+    """``solve_dispatches`` counts every device program of a refined solve
+    (refine=3 with a tolerance no sweep meets: three sweeps, two syncs)."""
+    A, _, _ = problem
+    rng = np.random.default_rng(5)
+    glu = GLU(A, dtype=jnp.float64, refine_tol=0.0)
+    if kind == "batched":
+        glu.factorize_batched(np.stack([A.data, 1.01 * A.data]))
+    else:
+        glu.factorize()
+    solve = {"single": lambda: glu.solve(rng.standard_normal(A.n), refine=3),
+             "batched": lambda: glu.solve_batched(
+                 rng.standard_normal((2, A.n)), refine=3),
+             "multi": lambda: glu.solve_multi(
+                 rng.standard_normal((2, A.n)), refine=3)}[kind]
+    per_solve = 1 + 1 + zeros + 3 * 5
+    solve()
+    info = glu.solve_info
+    assert np.all(info["refine_iters"] == 3) and info["host_syncs"] == 2
+    assert info["n_dispatches"] == 1
+    assert info["solve_dispatches"] == per_solve + 1
+    solve()                                 # |A| is already on the device
+    assert glu.solve_info["solve_dispatches"] == per_solve
+
+
 def test_glu_fused_matches_legacy_end_to_end(problem):
     A, _, _ = problem
     b = np.random.default_rng(4).standard_normal(A.n)
