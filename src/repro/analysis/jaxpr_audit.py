@@ -118,8 +118,6 @@ def audit_trisolve(solver, dtype=None) -> VerifyReport:
     """Audit a :class:`~repro.core.triangular.JaxTriangularSolver`'s fused
     full-schedule runner.  The trisolve contract is ZERO donated buffers:
     the caller retains both the factor values and the right-hand side."""
-    from ..core.triangular import _build_trisolve_runner
-
     rep = VerifyReport()
     if not solver.jit_schedule:
         rep.ran("audit_trisolve")
@@ -129,10 +127,7 @@ def audit_trisolve(solver, dtype=None) -> VerifyReport:
                 f"group ({len(fwd) + len(bwd)} groups), not one total")
         return rep
     planar = solver._planar
-    runner = solver._exec_cache.get_or_build(
-        ("trisolve", solver.plan.digest, "full", "single",
-         None, solver.layout),
-        lambda: _build_trisolve_runner("single", planar=planar, shard=None))
+    runner = solver._runner("single", "full")
     nnz, n = solver.plan.nnz, solver.plan.n
     if planar:
         vals = jnp.zeros((nnz, 2), dtype=dtype or jnp.float64)
@@ -142,6 +137,6 @@ def audit_trisolve(solver, dtype=None) -> VerifyReport:
         vals = jnp.zeros(nnz, dtype=dtype or jnp.float64)
         b = jnp.zeros(n, dtype=vals.dtype)
     fwd, bwd = solver._full_schedule
-    _audit_traced(runner, (vals, b, tuple(fwd), tuple(bwd)),
+    _audit_traced(runner, (vals, b, tuple(fwd), tuple(bwd), solver._tail),
                   name="trisolve", expect_donated=0, rep=rep)
     return rep

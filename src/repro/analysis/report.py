@@ -41,6 +41,7 @@ CODES = {
     "TRISOLVE_FWD_SET": "forward-solve entry set differs from L's",
     "TRISOLVE_BWD_RACE": "backward-solve entry reads a not-yet-final x",
     "TRISOLVE_BWD_SET": "backward-solve entry/column set differs from U's",
+    "TRISOLVE_DENSE_TAIL": "dense-tail trisolve step disagrees with the pattern",
     # reach closures
     "REACH_ADJ_MISMATCH": "plan DAG adjacency differs from the pattern's",
     "REACH_UNDER": "reach closure under-approximates (drops trisolve work)",

@@ -250,12 +250,14 @@ def _trisolve_steps(groups, width):
     return steps
 
 
-def verify_trisolver(solver, *, fwd_groups=None, bwd_groups=None
-                     ) -> VerifyReport:
+def verify_trisolver(solver, *, fwd_groups=None, bwd_groups=None,
+                     tail=None) -> VerifyReport:
     """Verify a built :class:`~repro.core.triangular.JaxTriangularSolver`
     full schedule against its plan (same step-timing discipline as
     :func:`verify_executor`, on the solution vector instead of the value
-    array)."""
+    array).  With a dense tail the dense step covers the L and U entries
+    inside the trailing block and divides its columns; ``tail`` overrides
+    the solver's block position map."""
     plan = solver.plan
     n, nnz = plan.n, plan.nnz
     rep = VerifyReport()
@@ -264,12 +266,31 @@ def verify_trisolver(solver, *, fwd_groups=None, bwd_groups=None
         fg, bg = solver._full_schedule
         fwd_groups = fg if fwd_groups is None else fwd_groups
         bwd_groups = bg if bwd_groups is None else bwd_groups
+    tail = solver._tail if tail is None else tail
     indptr = np.asarray(plan.indptr, dtype=np.int64)
     indices = np.asarray(plan.indices, dtype=np.int64)
     cols_of = np.repeat(np.arange(n, dtype=np.int64), np.diff(indptr))
     diag_idx = np.asarray(plan.diag_idx, dtype=np.int64)
     lower = indices > cols_of
     upper = indices < cols_of
+    # the dense step owns the block [c*, n) x [c*, n)
+    c_star = n
+    if (tail is None) != (solver.dense_tail_info is None):
+        rep.add("TRISOLVE_DENSE_TAIL",
+                "dense-tail step and dense_tail_info disagree on existence")
+        return rep
+    if tail is not None:
+        rep.ran("trisolve_dense_tail")
+        c_star = int(solver.dense_tail_info["c_star"])
+        size = n - c_star
+        # the solver holds the map column by column (transposed)
+        pos = np.asarray(tail).astype(np.int64).T
+        if (pos.shape != (size, size)
+                or not np.array_equal(pos,
+                                      _dense_tail_want(plan, c_star, size))):
+            rep.add("TRISOLVE_DENSE_TAIL",
+                    "dense block position map disagrees with the pattern")
+    in_tail = (indices >= c_star) & (cols_of >= c_star)
 
     # forward sweep: step t reads x[cols] (pre-step) and adds into x[rows]
     fsteps = _trisolve_steps(fwd_groups, 3)
@@ -294,7 +315,10 @@ def verify_trisolver(solver, *, fwd_groups=None, bwd_groups=None
         np.minimum.at(rmin, c, t)
         np.maximum.at(wmax, r, t)
         fvs.append(v)
-    got = np.sort(np.concatenate(fvs)) if fvs else np.zeros(0, dtype=np.int64)
+    # the dense step reads the tail of x after every sparse forward step
+    rmin[c_star:n] = np.minimum(rmin[c_star:n], len(fsteps))
+    fvs.append(np.flatnonzero(lower & in_tail))
+    got = np.sort(np.concatenate(fvs))
     if not np.array_equal(got, np.flatnonzero(lower)):
         rep.add("TRISOLVE_FWD_SET",
                 "executed forward entries are not exactly L's",
@@ -307,12 +331,18 @@ def verify_trisolver(solver, *, fwd_groups=None, bwd_groups=None
                 f"{int(rmin[c])}", col=c, n_bad=int(bad.sum()))
 
     # backward sweep: step t divides its level columns first (sequential in
-    # the step body), then its updates read x[cols] / write x[rows]
+    # the step body), then its updates read x[cols] / write x[rows].  With
+    # a dense tail, time 0 is the dense step (it divides the tail columns)
+    # and the level steps follow.
     bsteps = _trisolve_steps(bwd_groups, 5)
+    t0 = 0 if tail is None else 1
     t_div = np.full(n + 1, -1, dtype=np.int64)
     n_div = np.zeros(n + 1, dtype=np.int64)
     ents = []
-    for t, (lcols, ldiag, rows, cols, vidx) in enumerate(bsteps):
+    if tail is not None:
+        t_div[c_star:n] = 0
+        n_div[c_star:n] = 1
+    for t, (lcols, ldiag, rows, cols, vidx) in enumerate(bsteps, start=t0):
         if (np.any((lcols < 0) | (lcols > n))
                 or np.any((ldiag < 0) | (ldiag > nnz))
                 or np.any((vidx < 0) | (vidx > nnz))
@@ -346,7 +376,10 @@ def verify_trisolver(solver, *, fwd_groups=None, bwd_groups=None
         ts = np.concatenate([e[3] for e in ents])
     else:
         r = c = v = ts = np.zeros(0, dtype=np.int64)
-    if not np.array_equal(np.sort(v), np.flatnonzero(upper)):
+    # the dense step applies the U entries inside the block itself
+    dense_u = np.flatnonzero(upper & in_tail)
+    if not np.array_equal(np.sort(np.concatenate([v, dense_u])),
+                          np.flatnonzero(upper)):
         rep.add("TRISOLVE_BWD_SET",
                 "executed backward entries are not exactly strict U's",
                 got=len(v), want=int(upper.sum()))

@@ -345,7 +345,8 @@ class GLU:
                 self.plan, fuse=fuse_levels, fuse_buckets=fuse_buckets,
                 bucket_waste=bucket_waste, jit_schedule=jit_schedule,
                 executable_cache=executable_cache, layout=self.layout.name,
-                shard=self._shard)
+                shard=self._shard,
+                dense_tail=self._factorizer.dense_tail_info)
         self._vals: Optional[jnp.ndarray] = None
         self._vals_batch: Optional[jnp.ndarray] = None
         self._a_vals: Optional[jnp.ndarray] = None
@@ -710,6 +711,10 @@ class GLU:
             "n_groups": self._factorizer.n_groups,
             "n_dispatches": self._factorizer.last_n_dispatches,
             "solve_dispatches": None,
+            # the latest trisolve's dense-tail size (0: walked by levels)
+            # and its padded gather/scatter entries, forward and backward
+            "trisolve_dense_tail": None,
+            "trisolve_indexed_entries": None,
             # mode-adaptive execution surface: which storage layout the
             # factors use, and — when any Pallas-eligible work was routed
             # off the Pallas path — why (None means fully active)
@@ -748,6 +753,9 @@ class GLU:
         self._info.update(rinfo)
         self._info["solve_dispatches"] = (self._solver.last_n_dispatches
                                           + launched)
+        self._info["trisolve_dense_tail"] = self._solver.last_dense_tail
+        self._info["trisolve_indexed_entries"] = (
+            self._solver.last_indexed_entries)
 
     @property
     def refine_converged(self):

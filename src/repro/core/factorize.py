@@ -386,17 +386,25 @@ def _find_dense_tail(plan: FactorizePlan, min_size: int = 64,
     return int(smin[c_star]), int(c_star)
 
 
-def _build_dense_tail(plan: FactorizePlan, c_star: int, pad_key: int):
+def dense_tail_positions(plan: FactorizePlan, c_star: int,
+                         padded: int) -> np.ndarray:
+    """(padded, padded) value index of each entry of the trailing block
+    [c*, n) x [c*, n); ``nnz`` (the fill/drop pad) where the pattern has
+    none."""
+    indptr = np.asarray(plan.indptr, dtype=np.int64)
+    indices = np.asarray(plan.indices, dtype=np.int64)
+    cols = np.repeat(np.arange(plan.n, dtype=np.int64), np.diff(indptr))
+    m = (indices >= c_star) & (cols >= c_star)
+    pos = np.full((padded, padded), plan.nnz, dtype=np.int32)
+    pos[indices[m] - c_star, cols[m] - c_star] = np.flatnonzero(m)
+    return pos
+
+
+def _build_dense_tail(plan: FactorizePlan, c_star: int):
     """(positions (Np,Np) into vals, eye mask, Np) for the trailing block."""
-    n = plan.n
-    size = n - c_star
+    size = plan.n - c_star
     Np = ((size + 127) // 128) * 128
-    pos = np.full((Np, Np), pad_key, dtype=np.int32)
-    for j in range(c_star, n):
-        s, e = int(plan.indptr[j]), int(plan.indptr[j + 1])
-        rows = plan.indices[s:e]
-        m = rows >= c_star
-        pos[rows[m] - c_star, j - c_star] = np.arange(s, e, dtype=np.int32)[m]
+    pos = dense_tail_positions(plan, c_star, Np)
     eye = np.zeros((Np, Np), dtype=np.float32)
     ii = np.arange(size, Np)
     eye[ii, ii] = 1.0
@@ -811,7 +819,7 @@ class JaxFactorizer:
             found = _find_dense_tail(plan, density=dense_tail_density)
             if found is not None:
                 level_cut, c_star = found
-                pos, eye, Np = _build_dense_tail(plan, c_star, pad_key)
+                pos, eye, Np = _build_dense_tail(plan, c_star)
                 self.dense_tail_info = dict(level_cut=level_cut, c_star=c_star,
                                             size=plan.n - c_star, padded=Np)
                 self._dense_tail = (pos, eye)

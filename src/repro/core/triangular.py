@@ -16,6 +16,16 @@ the pruned solve is bit-identical to the full one on the reach.  Pruned
 schedules are cached per rhs pattern (the contract is many solves per
 pattern: a fixed excitation across a sweep).
 
+Dense trailing block: when the factorizer finishes the columns [c*, n) as
+one dense LU (``JaxFactorizer.dense_tail_info``), the solve runs that block
+as dense substitution instead of walking its hundreds of one-column levels
+by indexed gather and scatter.  A solve is then: the prefix's forward
+levels (their L entries into tail rows included), one gather of the
+factored block from ``vals``, unit-lower forward and upper backward
+substitution on it, and the prefix's backward levels re-levelled on the
+prefix columns, whose first level also subtracts the tail columns' U
+entries from the prefix rows.  Planar solves keep the level walk.
+
 Refinement runs on whatever system the factors describe (for the GLU facade
 that is the scaled + permuted one): each sweep computes ``r = b - A x`` with
 a sparse SpMV of A's values, the componentwise backward error
@@ -30,6 +40,7 @@ from __future__ import annotations
 
 from collections import OrderedDict
 from functools import partial
+from typing import Optional
 
 import numpy as np
 
@@ -41,9 +52,14 @@ from ..kernels.ops import masked_correction, spmv
 from ..sparse.layout import pack_planes, pdiv, pmul, unpack_planes
 from ..spans import named, span
 from .executor import resolve_executable_cache
+from .factorize import dense_tail_positions
 from .plan import FactorizePlan, bucketize, choose_buckets, pow2_pad
 
 __all__ = ["JaxTriangularSolver", "trisolve_numpy"]
+
+# unroll factor of the dense-tail substitution loops: 8 takes a 726-step
+# forward + backward pair from 6.73 to 6.20 ms on a TPU v5e (f64)
+_DENSE_TAIL_UNROLL = 8
 
 
 def trisolve_numpy(plan: FactorizePlan, vals: np.ndarray, b: np.ndarray) -> np.ndarray:
@@ -198,6 +214,74 @@ _bwd_group_planar_multi = partial(jax.jit, donate_argnums=(1,))(
              in_axes=(None, 0, None, None, None, None, None)))
 
 
+# -- dense trailing block ----------------------------------------------------
+
+def _prefix_bwd_levels(plan: FactorizePlan, c_star: int):
+    """Backward levels of the prefix columns [0, c*) once the tail is
+    solved.  Level 0 applies the tail columns' U entries in prefix rows
+    (their x is final) beside the prefix columns that wait on nothing, so
+    a prefix column waits on the tail at most one level.  Returns the
+    plan's ``bwd_*`` arrays (ptr, rows, cols, vidx, level_cols, col_ptr)
+    restricted to prefix rows and re-levelled."""
+    rows, cols, vidx = plan.bwd_rows, plan.bwd_cols, plan.bwd_vidx
+    lev = np.zeros(plan.n, dtype=np.int64)
+    # the plan's levels are a topological order, so a column's new level
+    # is final before its own entries are visited; tail columns stay at 0
+    for l in range(len(plan.bwd_ptr) - 1):
+        s, e = int(plan.bwd_ptr[l]), int(plan.bwd_ptr[l + 1])
+        rr = rows[s:e]
+        m = rr < c_star
+        if m.any():
+            np.maximum.at(lev, rr[m], lev[cols[s:e][m]] + 1)
+    keep = rows < c_star
+    rows, cols, vidx = rows[keep], cols[keep], vidx[keep]
+    srt = np.argsort(lev[cols], kind="stable")
+    rows, cols, vidx = rows[srt], cols[srt], vidx[srt]
+    nlev = int(lev[:c_star].max(initial=0)) + 1
+    ptr = np.searchsorted(lev[cols], np.arange(nlev + 1))
+    level_cols = np.argsort(lev[:c_star], kind="stable").astype(np.int64)
+    col_ptr = np.searchsorted(lev[level_cols], np.arange(nlev + 1))
+    return ptr, rows, cols, vidx, level_cols, col_ptr
+
+
+def _tail_step_body(vals, x, tail_cols):
+    """Dense unit-lower forward then upper backward substitution of
+    x[c*:] on the factored trailing block, gathered from ``vals`` column
+    by column: row j of ``tail_cols`` holds the value indices of the
+    block's column j (``nnz``, read as 0, where the pattern has none).
+    Each step takes one column of L or U, elementwise with no reduction,
+    so its rounding is the same batched, sharded or not."""
+    size = tail_cols.shape[0]
+    c = x.shape[0] - size
+    cols = vals.at[tail_cols].get(mode="fill", fill_value=0.0)
+    i = jnp.arange(size)
+
+    def fwd(j, y):
+        col = jax.lax.dynamic_index_in_dim(cols, j, keepdims=False)
+        yj = jax.lax.dynamic_index_in_dim(y, j, keepdims=False)
+        return jnp.where(i > j, y - col * yj, y)
+
+    def bwd(k, y):
+        j = size - 1 - k
+        col = jax.lax.dynamic_index_in_dim(cols, j, keepdims=False)
+        yj = (jax.lax.dynamic_index_in_dim(y, j, keepdims=False)
+              / jax.lax.dynamic_index_in_dim(col, j, keepdims=False))
+        return jnp.where(i < j, y - col * yj, jnp.where(i == j, yj, y))
+
+    y = jax.lax.fori_loop(0, size, fwd, x[c:], unroll=_DENSE_TAIL_UNROLL)
+    y = jax.lax.fori_loop(0, size, bwd, y, unroll=_DENSE_TAIL_UNROLL)
+    return jnp.concatenate([x[:c], y])
+
+
+# Per-kind twins of the dense-tail step for the ``jit_schedule=False``
+# path: one dispatch.
+_TAIL_STEP = {
+    "single": jax.jit(_tail_step_body),
+    "batched": jax.jit(jax.vmap(_tail_step_body, in_axes=(0, 0, None))),
+    "multi": jax.jit(jax.vmap(_tail_step_body, in_axes=(None, 0, None))),
+}
+
+
 # -- whole-schedule fused trisolve -----------------------------------------
 #
 # One jitted program runs the forward sweep, the backward sweep, and the
@@ -206,19 +290,25 @@ _bwd_group_planar_multi = partial(jax.jit, donate_argnums=(1,))(
 # factors) nor ``b`` (caller's rhs) is donated, which also removes the
 # defensive rhs copy the per-group path needs.
 
-def _solve_schedule_body(vals, b, fwd, bwd):
+def _solve_schedule_body(vals, b, fwd, bwd, tail=None):
+    """``tail`` (the block's column position map, or None) runs the
+    dense-tail step between the prefix walks."""
     x = jnp.asarray(b, dtype=vals.dtype)
     for g in fwd:
         x = _fwd_group_body(vals, x, *g)
+    if tail is not None:
+        x = _tail_step_body(vals, x, tail)
     for g in bwd:
         x = _bwd_group_body(vals, x, *g)
     return x
 
 
-def _solve_schedule_planar_body(vals, b, fwd, bwd):
+def _solve_schedule_planar_body(vals, b, fwd, bwd, tail=None):
     # planes in, native complex out: the rhs is packed INSIDE the fused
     # program and the solution unpacked at the end, so a planar triangular
-    # solve still presents the complex interface in ONE dispatch
+    # solve still presents the complex interface in ONE dispatch.  Planar
+    # solves walk the whole schedule: no dense-tail step
+    assert tail is None
     x = pack_planes(b, vals.dtype)
     for g in fwd:
         x = _fwd_group_planar_body(vals, x, *g)
@@ -229,21 +319,23 @@ def _solve_schedule_planar_body(vals, b, fwd, bwd):
 
 def _build_trisolve_runner(kind: str, planar: bool = False, shard=None):
     body = _solve_schedule_planar_body if planar else _solve_schedule_body
+    # arguments: (vals, b, fwd, bwd, tail); tail is None without a dense
+    # tail
     if kind == "single":
         fn = body
     elif kind == "batched":
-        fn = jax.vmap(body, in_axes=(0, 0, None, None))
+        fn = jax.vmap(body, in_axes=(0, 0, None, None, None))
     else:  # "multi"
-        fn = jax.vmap(body, in_axes=(None, 0, None, None))
+        fn = jax.vmap(body, in_axes=(None, 0, None, None, None))
     if shard is not None:
         if kind != "batched":
             raise ValueError("scenario sharding requires the batched kind")
-        # value and rhs batches split along the scenario axes, the level
+        # value and rhs batches split along the scenario axes, the
         # schedule is replicated; each shard's trisolve stays one dispatch.
         # Rows never interact, so the result is bit-identical to unsharded.
         bspec = shard.spec
         fn = jax.shard_map(fn, mesh=shard.mesh,
-                           in_specs=(bspec, bspec, P(), P()),
+                           in_specs=(bspec, bspec, P(), P(), P()),
                            out_specs=bspec, check_vma=False)
     return jax.jit(named("glu_trisolve", fn))
 
@@ -258,7 +350,11 @@ class JaxTriangularSolver:
     def __init__(self, plan: FactorizePlan, fuse: bool = True,
                  fuse_buckets: bool = True, bucket_waste: float = 4.0,
                  jit_schedule: bool = True, executable_cache="default",
-                 layout: str = "native", shard=None):
+                 layout: str = "native", shard=None,
+                 dense_tail: Optional[dict] = None):
+        """``dense_tail``: the factorizer's ``dense_tail_info`` (or None).
+        When given, on the native layout, the trailing block is solved
+        densely, on the block each solve gathers from ``vals``."""
         if layout not in ("native", "planar"):
             raise ValueError(
                 f"layout must be 'native' or 'planar', got {layout!r} "
@@ -281,18 +377,40 @@ class JaxTriangularSolver:
         # dispatch count of the most recent solve* call (1 on the fused
         # path; one per level group plus the rhs copy otherwise)
         self.last_n_dispatches = 0
+        # the most recent trisolve's dense-tail size (0: not engaged) and
+        # its padded gather/scatter entries, forward and backward
+        self.last_dense_tail = 0
+        self.last_indexed_entries = 0
+        self.dense_tail_info = None if self._planar else dense_tail
+        self._tail = None
+        self._n_fwd_levels = len(plan.fwd_ptr) - 1
+        self._bwd_levels = (plan.bwd_ptr, plan.bwd_rows, plan.bwd_cols,
+                            plan.bwd_vidx, plan.bwd_level_cols,
+                            plan.bwd_col_ptr)
+        if self.dense_tail_info is not None:
+            # the sparse walks cover the prefix columns [0, c*) alone: the
+            # forward levels before the cut, the backward levels re-levelled
+            c_star = int(self.dense_tail_info["c_star"])
+            self._n_fwd_levels = int(self.dense_tail_info["level_cut"])
+            self._bwd_levels = _prefix_bwd_levels(plan, c_star)
+            size = plan.n - c_star
+            self._tail = jnp.asarray(
+                dense_tail_positions(plan, c_star, size).T)
         self._full_schedule = self._build_schedule(None, None)
         if self.shard is not None:
             # schedule index arrays are replicated once so the sharded
             # runner never re-lays them out per call
             self._full_schedule = self.shard.replicate(self._full_schedule)
+            if self._tail is not None:
+                self._tail = self.shard.replicate(self._tail)
         self._sparse_schedules: OrderedDict = OrderedDict()
 
     def _build_schedule(self, fwd_mask, bwd_mask):
         """Level-group schedule as (fwd_groups, bwd_groups).  ``fwd_mask`` /
         ``bwd_mask`` (boolean (n,) column masks) restrict the schedule to
         the masked columns; levels left empty are dropped entirely (fewer
-        scheduled steps is where the sparse-RHS win comes from).
+        scheduled steps is where the sparse-RHS win comes from).  With a
+        dense tail the groups cover the prefix columns only.
 
         With ``fuse_buckets`` the per-level pow2 pads are quantized up to a
         geometric ladder built from THIS schedule's level-size histogram, so
@@ -333,8 +451,7 @@ class JaxTriangularSolver:
             return groups
 
         fwd_raw = []
-        nlev = len(plan.fwd_ptr) - 1
-        for l in range(nlev):
+        for l in range(self._n_fwd_levels):
             s, e = int(plan.fwd_ptr[l]), int(plan.fwd_ptr[l + 1])
             rows = plan.fwd_rows[s:e]
             cols = plan.fwd_cols[s:e]
@@ -360,15 +477,15 @@ class JaxTriangularSolver:
         fwd_groups = build_groups(fwd_items)
 
         bwd_raw = []
-        nulev = len(plan.bwd_ptr) - 1
+        bptr, brows, bcols, bvidx, blcols, bcol_ptr = self._bwd_levels
         diag = plan.diag_idx
-        for l in range(nulev):
-            s, e = int(plan.bwd_ptr[l]), int(plan.bwd_ptr[l + 1])
-            cs, ce = int(plan.bwd_col_ptr[l]), int(plan.bwd_col_ptr[l + 1])
-            lcols = plan.bwd_level_cols[cs:ce]
-            rows = plan.bwd_rows[s:e]
-            cols = plan.bwd_cols[s:e]
-            vidx = plan.bwd_vidx[s:e]
+        for l in range(len(bptr) - 1):
+            s, e = int(bptr[l]), int(bptr[l + 1])
+            cs, ce = int(bcol_ptr[l]), int(bcol_ptr[l + 1])
+            lcols = blcols[cs:ce]
+            rows = brows[s:e]
+            cols = bcols[s:e]
+            vidx = bvidx[s:e]
             if bwd_mask is not None:
                 keepc = bwd_mask[lcols]
                 keepu = bwd_mask[cols]
@@ -448,17 +565,40 @@ class JaxTriangularSolver:
         key = self._normalize_pattern(rhs_pattern).tobytes()
         return fwd, bwd, key.hex()
 
-    def _run_fused(self, kind: str, vals, x, fwd, bwd, sid: str):
-        shard = self.shard
-        if shard is not None and (kind != "batched"
-                                  or vals.shape[0] % shard.n_shards != 0):
-            shard = None
-        runner = self._exec_cache.get_or_build(
+    def _schedule(self, rhs_pattern):
+        """(fwd, bwd, tail, schedule_id) of one trisolve; records its
+        dense-tail size and padded gather/scatter entries.  ``tail`` is
+        None when the plan has none or the rhs's forward reach never enters
+        it; the tail is dense, so a reach that enters it takes the whole
+        step."""
+        fwd, bwd, sid = self._groups_for(rhs_pattern)
+        tail = self._tail
+        if tail is not None and rhs_pattern is not None:
+            freach = self.schedule_for_pattern(rhs_pattern)[2]
+            if not (len(freach)
+                    and freach[-1] >= self.dense_tail_info["c_star"]):
+                tail = None
+        self.last_indexed_entries = (
+            sum(int(np.prod(g[0].shape)) for g in fwd)
+            + sum(int(np.prod(g[2].shape)) for g in bwd))
+        self.last_dense_tail = (0 if tail is None
+                                else int(self.dense_tail_info["size"]))
+        return fwd, bwd, tail, sid
+
+    def _runner(self, kind: str, sid: str, shard=None):
+        return self._exec_cache.get_or_build(
             ("trisolve", self.plan.digest, sid, kind,
              None if shard is None else shard.descriptor, self.layout),
             lambda: _build_trisolve_runner(kind, planar=self._planar,
                                            shard=shard))
-        out = runner(vals, x, tuple(fwd), tuple(bwd))
+
+    def _run_fused(self, kind: str, vals, x, fwd, bwd, tail, sid: str):
+        shard = self.shard
+        if shard is not None and (kind != "batched"
+                                  or vals.shape[0] % shard.n_shards != 0):
+            shard = None
+        out = self._runner(kind, sid, shard)(vals, x, tuple(fwd), tuple(bwd),
+                                             tail)
         self.last_n_dispatches = 1
         return out
 
@@ -475,10 +615,10 @@ class JaxTriangularSolver:
         """With ``rhs_pattern`` (indices of b's nonzero support) the level
         schedule is pruned to the reach closure of the pattern; ``b`` MUST
         be zero outside it (the facade validates this)."""
-        fwd, bwd, sid = self._groups_for(rhs_pattern)
+        fwd, bwd, tail, sid = self._schedule(rhs_pattern)
         if self.jit_schedule:
             return self._run_fused("single", jnp.asarray(vals),
-                                   jnp.asarray(b), fwd, bwd, sid)
+                                   jnp.asarray(b), fwd, bwd, tail, sid)
         if self._planar:
             # pack_planes always allocates, so the donated running buffer
             # never aliases the caller's rhs
@@ -496,9 +636,12 @@ class JaxTriangularSolver:
         x = jnp.array(b, dtype=vals.dtype, copy=True)
         for g in fwd:
             x = _fwd_group(vals, x, *g)
+        if tail is not None:
+            x = _TAIL_STEP["single"](vals, x, tail)
         for g in bwd:
             x = _bwd_group(vals, x, *g)
-        self.last_n_dispatches = len(fwd) + len(bwd) + 1
+        self.last_n_dispatches = (len(fwd) + len(bwd) + 1
+                                  + (tail is not None))
         return x
 
     def solve_batched(self, vals_batch: jnp.ndarray, b_batch,
@@ -507,7 +650,7 @@ class JaxTriangularSolver:
         and right-hand side ``b_batch[i]`` — B solves in lockstep.  A
         ``rhs_pattern`` is shared by the whole batch (union support)."""
         vals = jnp.asarray(vals_batch)
-        fwd, bwd, sid = self._groups_for(rhs_pattern)
+        fwd, bwd, tail, sid = self._schedule(rhs_pattern)
         b = jnp.asarray(b_batch)
         want = 3 if self._planar else 2
         if vals.ndim != want or b.ndim != 2 or vals.shape[0] != b.shape[0]:
@@ -516,7 +659,7 @@ class JaxTriangularSolver:
                 f"expected {shape} values and (B, n) rhs, got "
                 f"{vals.shape} and {b.shape}")
         if self.jit_schedule:
-            return self._run_fused("batched", vals, b, fwd, bwd, sid)
+            return self._run_fused("batched", vals, b, fwd, bwd, tail, sid)
         if self._planar:
             x = pack_planes(b, vals.dtype)
             for g in fwd:
@@ -529,9 +672,12 @@ class JaxTriangularSolver:
         x = jnp.array(b, dtype=vals.dtype, copy=True)
         for g in fwd:
             x = _fwd_group_batched(vals, x, *g)
+        if tail is not None:
+            x = _TAIL_STEP["batched"](vals, x, tail)
         for g in bwd:
             x = _bwd_group_batched(vals, x, *g)
-        self.last_n_dispatches = len(fwd) + len(bwd) + 1
+        self.last_n_dispatches = (len(fwd) + len(bwd) + 1
+                                  + (tail is not None))
         return x
 
     def solve_multi(self, vals: jnp.ndarray, b_multi,
@@ -541,7 +687,7 @@ class JaxTriangularSolver:
         for all K rhs (the adjoint/sensitivity workload).  A ``rhs_pattern``
         is the union support of all rows."""
         vals = jnp.asarray(vals)
-        fwd, bwd, sid = self._groups_for(rhs_pattern)
+        fwd, bwd, tail, sid = self._schedule(rhs_pattern)
         b = jnp.asarray(b_multi)
         want = 2 if self._planar else 1
         if vals.ndim != want or b.ndim != 2:
@@ -550,7 +696,7 @@ class JaxTriangularSolver:
                 f"expected {shape} values and (K, n) rhs, got "
                 f"{vals.shape} and {b.shape}")
         if self.jit_schedule:
-            return self._run_fused("multi", vals, b, fwd, bwd, sid)
+            return self._run_fused("multi", vals, b, fwd, bwd, tail, sid)
         if self._planar:
             x = pack_planes(b, vals.dtype)
             for g in fwd:
@@ -562,9 +708,12 @@ class JaxTriangularSolver:
         x = jnp.array(b, dtype=vals.dtype, copy=True)
         for g in fwd:
             x = _fwd_group_multi(vals, x, *g)
+        if tail is not None:
+            x = _TAIL_STEP["multi"](vals, x, tail)
         for g in bwd:
             x = _bwd_group_multi(vals, x, *g)
-        self.last_n_dispatches = len(fwd) + len(bwd) + 1
+        self.last_n_dispatches = (len(fwd) + len(bwd) + 1
+                                  + (tail is not None))
         return x
 
     # -- iterative refinement -------------------------------------------------

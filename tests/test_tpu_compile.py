@@ -111,6 +111,27 @@ def test_f64_factorize_and_trisolve_runners_compile(one_chip, zoo_matrix):
         vals, b, *_shapes((tuple(fwd), tuple(bwd)), one_chip)).compile()
 
 
+@pytest.mark.parametrize("kind", ["single", "batched"])
+def test_f64_dense_tail_trisolve_runner_compiles(one_chip, zoo_matrix, kind):
+    """The dense-tail trisolve: prefix levels, the block gathered from
+    ``vals`` and dense substitution on it, then the prefix backward levels,
+    as one float64 program."""
+    A = zoo_matrix
+    g = GLU(A)
+    info = g._factorizer.dense_tail_info
+    assert info is not None
+    solver = g._solver
+    fwd, bwd = solver._full_schedule
+    batch = (4,) if kind == "batched" else ()
+    vals = jax.ShapeDtypeStruct(batch + (g.nnz_filled,), jnp.float64,
+                                sharding=one_chip)
+    b = jax.ShapeDtypeStruct(batch + (A.n,), jnp.float64, sharding=one_chip)
+    c = _build_trisolve_runner(kind).lower(
+        vals, b, *_shapes((tuple(fwd), tuple(bwd), solver._tail), one_chip)
+    ).compile()
+    assert "tpu_custom_call" not in c.as_text()
+
+
 def test_f32_pallas_runner_compiles(one_chip, zoo_matrix):
     """The paper's kernel path: SEGMENTED levels and the dense tail as
     Mosaic kernels inside the one fused factorize program."""
