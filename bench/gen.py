@@ -1,14 +1,23 @@
-"""Matrix and value generators of the benchmark.
+"""Matrix and value helpers of the benchmark.
 
-Frozen copies of the program's synthetic circuit-matrix generators
-(``circuit_jacobian`` and ``rc_ladder`` as they stood when the benchmark was
-defined), so that a later change to the program's own copies cannot change
-what the benchmark measures.  They return plain CSC arrays: the benchmark
-hands the program only the matrix, never anything the program built.
+A configuration names its generator: ``make_matrix`` runs ``make(**args)``
+of ``bench/generators/<generator>.py``, which returns ``(n, (indptr,
+indices, data), Netlist)``; a new matrix class is one added file there.
+``circuit_jacobian`` and ``rc_ladder`` are frozen copies of the program's
+synthetic circuit-matrix generators as they stood when the benchmark was
+defined, so that a later change to the program's own copies cannot change
+what the benchmark measures.  Generators return plain CSC arrays: the
+benchmark hands the program only the matrix, never anything the program
+built.
 """
 from __future__ import annotations
 
+import importlib.util
+from pathlib import Path
+
 import numpy as np
+
+BENCH = Path(__file__).resolve().parent
 
 
 def csc_from_coo(n, rows, cols, vals):
@@ -74,78 +83,16 @@ class Netlist:
         return out
 
 
-def rc_ladder(n: int, seed: int = 0):
-    """RC ladder conductance matrix: tridiagonal, a leak on every node."""
-    rng = np.random.default_rng(seed)
-    g = rng.uniform(0.5, 2.0, size=n - 1)
-    i = np.arange(n - 1)
-    rows = np.concatenate([np.stack([i, i + 1, i, i + 1], 1).ravel(),
-                           np.arange(n)])
-    cols = np.concatenate([np.stack([i, i + 1, i + 1, i], 1).ravel(),
-                           np.arange(n)])
-    vals = np.concatenate([np.stack([g, g, -g, -g], 1).ravel(),
-                           np.full(n, 1e-2)])
-    net = Netlist(n, np.concatenate([i, i + 1]), np.concatenate([i + 1, i]),
-                  np.concatenate([-g, -g]), np.concatenate([i, i]), n - 1,
-                  1e-2)
-    return n, csc_from_coo(n, rows, cols, vals), net
-
-
-def circuit_jacobian(n: int, avg_degree: float = 4.0, n_rails: int = 0,
-                     rail_fanout: int = 64, asym: float = 0.1,
-                     pattern_asym: float = 0.0, seed: int = 0):
-    """Random circuit-Jacobian-like matrix: a mostly symmetric random
-    coupling pattern with ``asym`` value asymmetry, ``pattern_asym``
-    one-sided entries, ``n_rails`` high-degree nodes, and a diagonal of
-    row-sum dominance plus a 0.5 leak."""
-    rng = np.random.default_rng(seed)
-    m = int(n * avg_degree / 2)
-    a = rng.integers(0, n, size=m)
-    b = rng.integers(0, n, size=m)
-    keep = a != b
-    a, b = a[keep], b[keep]
-    g = rng.uniform(0.1, 1.0, size=len(a))
-    if pattern_asym > 0:
-        one_sided = rng.uniform(size=len(a)) < pattern_asym
-    else:
-        one_sided = np.zeros(len(a), dtype=bool)
-    two = ~one_sided
-    rows = [a, b[two]]
-    cols = [b, a[two]]
-    vals = [-g, -g[two] * (1.0 - asym * rng.uniform(0, 1, size=two.sum()))]
-    branch = [np.arange(len(a)), np.flatnonzero(two)]
-    n_branches = len(a)
-    for _ in range(n_rails):
-        node = rng.integers(0, n)
-        targets = rng.choice(n, size=min(rail_fanout, n - 1), replace=False)
-        targets = targets[targets != node]
-        gr = rng.uniform(0.1, 1.0, size=len(targets))
-        rows.extend([np.full(len(targets), node), targets])
-        cols.extend([targets, np.full(len(targets), node)])
-        vals.extend([-gr, -gr])
-        ids = n_branches + np.arange(len(targets))
-        branch.extend([ids, ids])
-        n_branches += len(targets)
-    rows = np.concatenate(rows)
-    cols = np.concatenate(cols)
-    vals = np.concatenate(vals)
-    net = Netlist(n, rows, cols, vals, np.concatenate(branch), n_branches,
-                  0.5)
-    diag = np.full(n, 0.5)
-    np.add.at(diag, rows, np.abs(vals))
-    rows = np.concatenate([rows, np.arange(n)])
-    cols = np.concatenate([cols, np.arange(n)])
-    vals = np.concatenate([vals, diag])
-    return n, csc_from_coo(n, rows, cols, vals), net
-
-
-GENERATORS = {"circuit_jacobian": circuit_jacobian, "rc_ladder": rc_ladder}
-
-
-def make_matrix(config: dict):
+def make_matrix(config: dict, bench: Path = BENCH):
     """The configuration's matrix and netlist:
-    ``(n, (indptr, indices, data), netlist)``."""
-    n, csc, net = GENERATORS[config["generator"]](**config["args"])
+    ``(n, (indptr, indices, data), netlist)``, made by ``make(**args)`` of
+    ``<bench>/generators/<config["generator"]>.py``."""
+    path = bench / "generators" / f"{config['generator']}.py"
+    spec = importlib.util.spec_from_file_location(
+        "bench_generator_" + config["generator"], path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    n, csc, net = mod.make(**config["args"])
     if not (np.array_equal(csc[0], net.indptr)
             and np.array_equal(csc[1], net.indices)):
         raise AssertionError("the netlist's pattern is not the matrix's")

@@ -14,8 +14,11 @@ the same clock (``repro.spans``):
 
 :func:`reduce` turns them into device time by program, program launches
 per call, and device idle time by the innermost span open in it.  Busy time
-still comes from ``XLA Ops`` alone: a module's interval only says which
-program the ops inside it belong to.
+comes from ``XLA Ops`` where the trace holds them: a module's interval then
+only says which program the ops inside it belong to.  A trace recorded
+without ops (a mix's ``trace_detail`` ``"programs"``) is reduced from the
+modules alone, aligned by :func:`shifted`; their busy time then includes
+the stalls inside each program.
 
 The device planes and the host are not on quite one clock: in a trace
 recorded on a TPU v5e a module starts up to 1.5 ms before the host call
@@ -38,6 +41,7 @@ SPAN_PREFIXES = ("bench.", GLU_PREFIX)
 CALL_SPANS = ("bench.step", "bench.sweep")
 MODULE_LINE = "XLA Modules"
 LAUNCH = "PJRT_LoadedExecutable_Execute"
+CORE_LAUNCH = "tpu::System::Execute"     # one per device a program runs on
 PROGRAM_PREFIX = "glu_"
 OTHER = "other"
 _DEVICE_PLANE = re.compile(r"/device:TPU:(\d+)")
@@ -59,6 +63,22 @@ def clock_offset(launches, modules) -> float:
     return max([0.0] + [h - m[1] for h, m in zip(sorted(launches), modules)])
 
 
+def device_offsets(launches, core_launches, modules):
+    """``{plane name: clock_offset}``.  A device that ran one module per
+    host launch is aligned to those launches.  One that did not (on a mesh,
+    a device other than the first runs the sharded programs alone) is
+    aligned to the runtime's launches on its own core, ``core_launches``
+    keyed by the core id that the device's id names."""
+    out = {}
+    for plane, evs in modules.items():
+        own = launches
+        if len(launches) != len(evs):
+            core = int(_DEVICE_PLANE.fullmatch(plane).group(1))
+            own = core_launches.get(core, [])
+        out[plane] = clock_offset(own, evs)
+    return out
+
+
 def read(path: str, device_ids):
     """``(spans, modules, offsets)`` from an ``.xplane.pb`` file.
 
@@ -66,12 +86,12 @@ def read(path: str, device_ids):
     ``bench.*`` or ``glu.*``.  ``modules``: ``{plane name: [(program name,
     start_ns, end_ns)]}`` from the ``XLA Modules`` line of the devices
     ``device_ids``, in time order; a used device without that line is an
-    error.  ``offsets``: ``{plane name: clock_offset}``.
+    error.  ``offsets``: :func:`device_offsets`.
     """
     from jax.profiler import ProfileData
 
     want = {int(i) for i in device_ids}
-    spans, modules, launches = [], {}, []
+    spans, modules, launches, core_launches = [], {}, [], {}
     for plane in ProfileData.from_file(path).planes:
         m = _DEVICE_PLANE.fullmatch(plane.name)
         if m and int(m.group(1)) in want:
@@ -88,6 +108,9 @@ def read(path: str, device_ids):
                 for ev in ln.events:
                     if ev.name == LAUNCH:
                         launches.append(ev.start_ns)
+                    elif ev.name == CORE_LAUNCH:
+                        core = dict(ev.stats).get("core_id")
+                        core_launches.setdefault(core, []).append(ev.start_ns)
                     elif ev.name.startswith(SPAN_PREFIXES):
                         spans.append((ev.name, ev.start_ns,
                                       ev.start_ns + ev.duration_ns))
@@ -95,8 +118,14 @@ def read(path: str, device_ids):
                       for p in modules}
     if missing:
         raise ValueError(f"no device plane for device ids {sorted(missing)}")
-    offsets = {p: clock_offset(launches, m) for p, m in modules.items()}
-    return spans, modules, offsets
+    return spans, modules, device_offsets(launches, core_launches, modules)
+
+
+def shifted(modules, offsets):
+    """Each device's events ``(name, start_ns, end_ns)`` moved onto the
+    host clock by its offset."""
+    return {d: [(n, s + offsets[d], e + offsets[d]) for n, s, e in evs]
+            for d, evs in modules.items()}
 
 
 def self_intervals(spans, lo: float, hi: float):

@@ -51,6 +51,20 @@ def half_batch(mp):
     mp.setattr(GLU, "refactorize_solve", refactorize_solve)
 
 
+def no_exchange(mp):
+    """A sharded sweep returns no shard's answers but the first one's: the
+    host reads the first chip's rows in place of every chip's."""
+    rs = GLU.refactorize_solve
+
+    def refactorize_solve(self, vals, rhs, *a, **k):
+        x = rs(self, vals, rhs, *a, **k)
+        per = len(x) // self.n_devices
+        return np.concatenate([x[:per]] * self.n_devices)
+
+    mp.setattr(GLU, "refactorize_solve", refactorize_solve)
+
+
 FAULTS = {"state_unchanged": state_unchanged,
           "answer_altered": answer_altered,
-          "half_batch": half_batch}
+          "half_batch": half_batch,
+          "no_exchange": no_exchange}

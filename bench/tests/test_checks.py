@@ -2,6 +2,7 @@
 fault a cell can have.  Each run drives the whole of ``run_cell`` except
 the look for a chip, at a size the CPU holds, with the timed path broken
 underneath where the test says so."""
+import json
 import os
 import subprocess
 import sys
@@ -10,6 +11,7 @@ from pathlib import Path
 
 import pytest
 
+import cpu_trace
 import run
 from faults import FAULTS
 
@@ -33,6 +35,13 @@ CASES = [
 ]
 WORKLOADS = ["randjac1879.newton", "rcladder17758.newton",
             "randjac1879.sweep16"]
+# readers of what the program itself records: GLU.setup_seconds,
+# solve_info and its spans and program names in the trace
+READS_PROGRAM = ["executor_build_s", "host_io_idle_ms.newton",
+                 "refine_idle_ms.newton", "programs_per_step.newton",
+                 "factorize_dev_ms.sweep", "solve_dev_ms.sweep",
+                 "trisolve_indexed_entries.newton",
+                 "trisolve_dense_tail.newton"]
 
 
 def small_config(workload):
@@ -87,20 +96,7 @@ def test_no_tpu_exits_without_a_result():
 def test_traced_run_reports_every_per_layer_metric(workload, monkeypatch):
     """The traced branch end to end on the CPU, whose trace has no TPU
     plane: the device is made busy exactly inside the call spans."""
-    import trace as tm
-
-    real = tm.read
-
-    def read(path, device_ids):
-        assert list(device_ids) == [0]
-        with pytest.raises(ValueError, match="no device plane"):
-            real(path, device_ids)
-        spans, _ = real(path, [])
-        calls = [s for s in spans
-                 if s[0] in ("bench.factorize", "bench.solve", "bench.sweep")]
-        return spans, {"/device:TPU:0": [("op", s, e) for _, s, e in calls]}
-
-    monkeypatch.setattr(tm, "read", read)
+    cpu_trace.install(monkeypatch)
     spec = run.load_spec()
     r = run.run_cell(workload, 2**31 + 23, 5.0, True, spec=spec,
                      config=small_config(workload), require_tpu=False,
@@ -110,6 +106,53 @@ def test_traced_run_reports_every_per_layer_metric(workload, monkeypatch):
     assert r["correct"]
     dev = r["device"]
     assert 0 < dev["busy_s"] <= dev["window_s"]
+    assert list(r["info"]["busy_s_by_device"]) == ["/device:TPU:0"]
     for name, m in r["metrics"].items():
         assert m["value"] >= 0, name
     assert r["breakdown"]["device_ops"][0][0] == "op"
+
+
+def test_a_newton_mix_on_several_chips_is_refused_before_it_runs():
+    spec = json.loads(json.dumps(run.load_spec()))
+    spec["workloads"].append({"name": "rcladder17758.newton.4chip",
+                              "config": "rcladder17758", "traffic": "newton",
+                              "chips": 4, "why": "x"})
+    with pytest.raises(ValueError, match="does not shard"):
+        run.run_cell("rcladder17758.newton.4chip", 1, 1.0, False, spec=spec,
+                     require_tpu=False)
+
+
+def test_every_new_reader_reads_nothing_without_the_programs_readings():
+    """On a traced run's ``ctx`` from a tree whose program writes no spans,
+    counters or setup record, the readers of them return nothing."""
+    ctx = {"plan_build_s": 1.0, "compile_s": 1.0, "kind": "newton",
+           "steps": 1, "refine_iters": [3],
+           "trace": {"window_s": 1.0, "busy_s": 0.5,
+                     "span_device_s": {"bench.factorize": 0.1,
+                                       "bench.solve": 0.4},
+                     "span_count": {"bench.factorize": 1, "bench.solve": 1}}}
+    for name in READS_PROGRAM:
+        for kind in ("newton", "sweep"):
+            assert run.load_reader(name)({**ctx, "kind": kind}) is None, name
+
+
+def test_a_traced_sweep_call_reads_no_solve_info_inside_the_window():
+    """Reading ``solve_info`` launches a program and waits for it.  A
+    traced Newton step reads it after the step, as it always has; a sweep
+    call leaves it to ``run_cell``, once the window has closed, so the
+    sweep cells' traced windows hold their calls alone."""
+    from traffic import Traffic
+
+    class Glu:
+        def refactorize_solve(self, vals, rhs, refine):
+            return rhs
+
+        @property
+        def solve_info(self):
+            raise AssertionError("solve_info read inside the window")
+
+    cfg = small_config("randjac1879.sweep16")
+    _, _, net = run.gen.make_matrix(cfg)
+    traffic = Traffic(run.load_mix("sweep16"), net, 2**31 + 31)
+    traffic.step(Glu(), 0, traced=True)
+    assert traffic.solve_info == [] and len(traffic.outputs) == 1

@@ -57,12 +57,16 @@ class Traffic:
         self.pool = pool
         self.outputs = []              # (pool index, solution(s)) per call
         self.refine_iters = []         # per traced Newton step
+        self.solve_info = []           # glu.solve_info per traced step
 
     def step(self, glu, i: int, traced: bool = False):
         """Run call ``i`` and keep its answer.  ``traced`` wraps the call
         in the benchmark's host spans, waits for a Newton step's factorize
-        inside its own span (the one sync the traced run adds) and reads
-        the step's refinement sweeps from ``solve_info`` after the step."""
+        inside its own span (the one sync the traced run adds) and, after
+        a Newton step, keeps a copy of ``glu.solve_info`` and the step's
+        refinement sweeps from it.  A sweep call reads nothing inside the
+        window: ``run.py`` reads its ``solve_info`` once the window has
+        closed."""
         k = i % self.pool
         span = _span if traced else _no_span
         with span("bench.step"):
@@ -81,7 +85,9 @@ class Traffic:
                                               refine=self.refine)
         self.outputs.append((k, x))
         if traced and self.kind == "newton":
-            self.refine_iters.append(glu.solve_info["refine_iters"])
+            info = glu.solve_info          # a new dict at every read
+            self.solve_info.append(info)
+            self.refine_iters.append(info["refine_iters"])
         return x
 
     def answers(self):
