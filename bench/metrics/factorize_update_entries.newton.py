@@ -1,0 +1,13 @@
+"""Padded scatter-add entries of the sparse levels one factorize walks
+before the dense block (``solve_info["factorize_update_entries"]``), mean
+over the traced Newton steps."""
+
+
+def read(ctx):
+    infos = ctx.get("solve_info")
+    if ctx.get("kind") != "newton" or not infos:
+        return None
+    v = [i.get("factorize_update_entries") for i in infos]
+    if any(x is None for x in v):
+        return None
+    return sum(v) / len(v)

@@ -711,6 +711,7 @@ class GLU:
             "n_groups": self._factorizer.n_groups,
             "n_dispatches": self._factorizer.last_n_dispatches,
             "solve_dispatches": None,
+            **self._factorize_counters(),
             # the latest trisolve's dense-tail size (0: walked by levels)
             # and its padded gather/scatter entries, forward and backward
             "trisolve_dense_tail": None,
@@ -734,6 +735,16 @@ class GLU:
                               else self.verify_report.summary()),
         }
 
+    def _factorize_counters(self) -> dict:
+        """What one factorize walks, set on the host from the built groups:
+        sparse levels before the dense block, their padded scatter-add
+        entries, and the dense block's columns (0 if none)."""
+        f = self._factorizer
+        tail = f.dense_tail_info
+        return {"factorize_levels": f.sparse_levels,
+                "factorize_update_entries": f.update_entries,
+                "factorize_dense_tail": 0 if tail is None else tail["size"]}
+
     def _set_solve_info(self, rinfo: dict, launched: int) -> None:
         """Record the latest solve; ``launched`` counts the device programs
         the facade launched around the solver's own (the lazy |A|)."""
@@ -742,6 +753,7 @@ class GLU:
                           "min_diag": None, "n_perturbed": None,
                           "n_groups": self._factorizer.n_groups,
                           "n_dispatches": None,
+                          **self._factorize_counters(),
                           "layout": self.layout.name,
                           "pallas_disabled_reason":
                               self._factorizer.pallas_disabled_reason,

@@ -924,6 +924,12 @@ class JaxFactorizer:
             groups.append(_Group(kind="dense", arrays=self._dense_tail,
                                  mode="dense", diag=tail_diag))
         self._groups = groups
+        # what one factorize walks before the dense block, from the built
+        # groups: sparse levels and their padded scatter-add entries
+        sparse = [g for g in groups if g.kind != "dense"]
+        self.sparse_levels = sum(g.n_levels for g in sparse)
+        self.update_entries = sum(int(np.prod(g.arrays[2].shape))
+                                  for g in sparse)
 
         # Static schedule signature + pytree views for the fused runner.
         self.jit_schedule = jit_schedule
